@@ -19,6 +19,8 @@ def main() -> None:
                          "(machine-readable sidecar to the CSV stream)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (fig4_loop_rearrangement, kernels_wallclock,
                    program_exec, program_lm, quant_profile, strip_storage,
                    table1_auto_vs_hand, table2_models, table3_load_balance)

@@ -68,6 +68,9 @@ class HardwareModel:
     # under MAC latency).  Expressed as FLOPs one "instruction slot" of
     # epilogue work costs relative to the main loop.
     epilogue_slot_flops: float = 0.0
+    # ``jax.Device.device_kind`` of the chip this model describes; empty
+    # for hardware JAX does not drive (Snowflake).
+    device_kind: str = ""
 
     # ---- derived quantities -------------------------------------------------
     @property
@@ -109,6 +112,7 @@ TPU_V5E = HardwareModel(
     ici_links_per_axis=2,          # 2D torus: two directions per axis
     load_units=4,                  # DMA streams we chunk against
     epilogue_slot_flops=8.0,
+    device_kind="TPU v5 lite",
 )
 
 # --- Snowflake (paper hardware), for the benchmark reproductions -------------

@@ -5,31 +5,48 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params: name moved across jax versions.
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams",
-                              getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from ..core.hw import TPU_V5E
 
 __all__ = ["pltpu", "compiler_params", "pad_to", "unpad", "apply_activation",
-           "ACTIVATIONS", "vmem_scratch", "default_interpret"]
+           "ACTIVATIONS", "vmem_scratch", "default_interpret",
+           "chunk_and_length", "resolve_impl"]
 
 
 def default_interpret() -> bool:
-    """Pallas runs in interpret mode off-TPU (this container is CPU)."""
+    """Pallas runs in interpret mode off-TPU (the CPU test path)."""
     return jax.default_backend() != "tpu"
 
 
-def compiler_params(dimension_semantics: tuple[str, ...],
-                    interpret: bool):
-    """Mosaic compiler params; omitted in interpret mode."""
-    if interpret or _CompilerParams is None:
-        return None
-    return _CompilerParams(dimension_semantics=dimension_semantics)
+def resolve_impl(impl: str, tpu: str = "pallas") -> str:
+    """``impl="auto"``: the Pallas kernel (``tpu``) on a TPU, the
+    pure-jnp reference elsewhere — the CPU test path.  Any explicit
+    impl passes through unchanged."""
+    if impl != "auto":
+        return impl
+    return tpu if jax.default_backend() == "tpu" else "reference"
+
+
+def compiler_params(dimension_semantics: tuple[str, ...]
+                    ) -> pltpu.CompilerParams:
+    """Mosaic compiler params (ignored in interpret mode).
+
+    The scoped-VMEM limit is the v5e model's VMEM capacity: the tilers
+    (core/tiling.py) size every working set to ``vmem_budget()``, a
+    fraction of that capacity, so the limit Mosaic enforces covers the
+    tile the schedule chose plus the compiler's own internal scratch.
+    Mosaic's default (16 MiB) would refuse any tile larger than that."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=TPU_V5E.vmem_bytes)
+
+
+def chunk_and_length(L: int, chunk: int) -> tuple[int, int]:
+    """(Q, Lp) for a chunked scan: the chunk is ``chunk``, or the whole
+    sequence rounded up to the 8-row sublane tile when shorter, and the
+    sequence is padded to Lp, a multiple of Q."""
+    Q = min(chunk, -(-L // 8) * 8)
+    return Q, -(-L // Q) * Q
 
 
 def pad_to(x: jax.Array, multiples: tuple[int, ...]) -> jax.Array:
@@ -71,5 +88,4 @@ def apply_activation(x: jax.Array, name: str | None) -> jax.Array:
 
 def vmem_scratch(shape, dtype):
     """VMEM scratch shape (works in interpret mode too)."""
-    assert pltpu is not None, "pallas tpu backend required"
     return pltpu.VMEM(shape, dtype)

@@ -10,7 +10,7 @@ realize the same schedule with different halo storage:
   (grid-blocked only on batch / output channels) and gathers each
   output-row strip *inside* the kernel body with a dynamic slice keyed
   off the strip program id.  Strip row offsets are affine
-  (``s * out_rows * stride``); when a caller needs non-affine offsets
+  (``s * out_rows``); when a caller needs non-affine offsets
   (ragged strip tables) it passes ``row_starts`` and the offsets are
   scalar-prefetched via ``PrefetchScalarGridSpec`` so the DMA address
   is known before the body runs.  No halo byte is ever duplicated in
@@ -38,11 +38,14 @@ Every grid dimension writes a disjoint output block and carries no
 cross-iteration state, so all dimensions are declared ``"parallel"``
 in ``compiler_params`` — Mosaic is free to double-buffer and reorder.
 
-The conv itself is implicit GEMM: for each (dy, dx) tap, a strided
-patch of the strip is contracted with w[dy, dx] on the MXU and
-accumulated in f32.  Epilogue fuses bias + activation + residual
-bypass (the paper's VMOV-on-writeback for ResNet), then the optional
-maxpool.
+The conv itself is implicit GEMM: for each (dy, dx) tap, a patch of
+the strip is contracted with w[dy, dx] on the MXU and accumulated in
+f32.  The kernels see unit-stride convs only: ops.py folds a stride s
+into channels first (space-to-depth), which turns a k x k stride-s conv
+into a ceil(k/s) x ceil(k/s) unit-stride one over s*s*Cin channels —
+Mosaic slices vectors at unit stride only.  Epilogue fuses bias +
+activation + residual bypass (the paper's VMOV-on-writeback for
+ResNet), then the optional maxpool.
 """
 from __future__ import annotations
 
@@ -52,32 +55,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import apply_activation, compiler_params, default_interpret, pltpu
+from ..common import (apply_activation, compiler_params, default_interpret,
+                      pltpu, vmem_scratch)
 from ...core.dataflow import Dataflow
 from ...core.ir import pool_out
 
-__all__ = ["conv2d_strips_pallas", "conv2d_virtual_pallas"]
+__all__ = ["conv2d_strips_pallas", "conv2d_virtual_pallas", "compute_cols"]
 
 
-def _implicit_gemm(x, w_ref, rows, OW, stride, kh, kw, kpt):
-    """Accumulate the (dy, dx) taps of an implicit GEMM in f32.
+def compute_cols(OW: int) -> int:
+    """Output columns the kernel computes: OW rounded up to the f32
+    sublane tile, so each tap's (rows, cols, Cin) patch flattens to
+    (rows * cols, Cin) without a relayout.  The extra columns read
+    right-padding and are dropped before the epilogue; callers pad the
+    maps to ``compute_cols(OW) + kw - 1`` columns."""
+    return -(-OW // 8) * 8
 
-    x: (in_rows, Wp, Cin) input window; returns (rows, OW, kpt)."""
-    Cin = x.shape[-1]
-    acc = jnp.zeros((rows * OW, kpt), jnp.float32)
+
+def _implicit_gemm(x_ref, r0, w_ref, rows, OW, kh, kw, kpt):
+    """Accumulate the (dy, dx) taps of a unit-stride implicit GEMM in f32.
+
+    x_ref: (1, Hp, Wp, Cin) maps block; r0: the strip's first input row.
+    Each tap is a ref load at a (dy, dx) offset.  Returns (rows, OW,
+    kpt)."""
+    Cin = x_ref.shape[-1]
+    cols = compute_cols(OW)
+    acc = jnp.zeros((rows * cols, kpt), jnp.float32)
     for dy in range(kh):
         for dx in range(kw):
-            patch = jax.lax.slice(
-                x, (dy, dx, 0),
-                (dy + (rows - 1) * stride + 1,
-                 dx + (OW - 1) * stride + 1, Cin),
-                (stride, stride, 1))               # (rows, OW, Cin)
+            patch = x_ref[0, pl.ds(r0 + dy, rows), pl.ds(dx, cols), :]
             acc += jax.lax.dot_general(
-                patch.reshape(rows * OW, Cin).astype(jnp.float32),
+                patch.astype(jnp.float32).reshape(rows * cols, Cin),
                 w_ref[dy, dx].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-    return acc.reshape(rows, OW, kpt)
+    return acc.reshape(rows, cols, kpt)[:, :OW]
 
 
 def _epilogue(acc, bias_ref, byp, activation, bypass_first):
@@ -93,7 +105,7 @@ def _epilogue(acc, bias_ref, byp, activation, bypass_first):
 
 
 # --- materialized strips (paper-faithful baseline) ---------------------------------
-def _body(x_ref, w_ref, *rest, out_rows, OW, stride, kh, kw,
+def _body(x_ref, w_ref, *rest, out_rows, OW, kh, kw,
           activation, out_dtype, has_bias, has_bypass,
           bypass_first=False):
     refs = list(rest)
@@ -101,22 +113,23 @@ def _body(x_ref, w_ref, *rest, out_rows, OW, stride, kh, kw,
     byp_ref = refs.pop(0) if has_bypass else None
     o_ref = refs.pop(0)
 
-    acc = _implicit_gemm(x_ref[0], w_ref, out_rows, OW, stride, kh, kw,
+    acc = _implicit_gemm(x_ref, 0, w_ref, out_rows, OW, kh, kw,
                          o_ref.shape[-1])
     byp = byp_ref[0] if byp_ref is not None else None
     acc = _epilogue(acc, bias_ref, byp, activation, bypass_first)
     o_ref[0] = acc.astype(out_dtype)
 
 
-def conv2d_strips_pallas(strips, w, *, out_rows: int, OW: int, stride: int,
-                         kpt: int, bias=None, activation: str | None = None,
+def conv2d_strips_pallas(strips, w, *, out_rows: int, OW: int, kpt: int,
+                         bias=None, activation: str | None = None,
                          bypass=None, bypass_first: bool = False,
                          out_dtype=None,
                          dataflow: Dataflow = Dataflow.MAPS_RESIDENT,
                          interpret: bool | None = None) -> jax.Array:
     """strips: (NS, in_rows, Wp, Cin) halo-augmented row strips already
-    materialized in HBM; w: (kh, kw, Cin, Cout); bypass:
-    (NS, out_rows, OW, Cout) or None.  Returns (NS, out_rows, OW, Cout)."""
+    materialized in HBM; w: (kh, kw, Cin, Cout), unit stride (ops.py
+    folds a stride into channels); bypass: (NS, out_rows, OW, Cout) or
+    None.  Returns (NS, out_rows, OW, Cout)."""
     if interpret is None:
         interpret = default_interpret()
     NS, in_rows, Wp, Cin = strips.shape
@@ -153,13 +166,12 @@ def conv2d_strips_pallas(strips, w, *, out_rows: int, OW: int, stride: int,
         operands.append(bypass)
 
     body = functools.partial(
-        _body, out_rows=out_rows, OW=OW, stride=stride, kh=kh, kw=kw,
+        _body, out_rows=out_rows, OW=OW, kh=kh, kw=kw,
         activation=activation, out_dtype=out_dtype, has_bias=has_bias,
         has_bypass=has_bypass, bypass_first=bypass_first)
     # Output tiles are disjoint across both grid dims: parallel semantics
     # let Mosaic double-buffer the streamed operand.
-    params = compiler_params(("parallel", "parallel"), interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
+    assert Wp >= compute_cols(OW) + kw - 1, (Wp, OW, kw)
     return pl.pallas_call(
         body,
         grid=grid,
@@ -167,14 +179,14 @@ def conv2d_strips_pallas(strips, w, *, out_rows: int, OW: int, stride: int,
         out_specs=pl.BlockSpec((1, out_rows, OW, kpt), o_idx),
         out_shape=jax.ShapeDtypeStruct((NS, out_rows, OW, Cout), out_dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(("parallel", "parallel")),
     )(*operands)
 
 
 # --- virtual strips (zero-copy) ----------------------------------------------------
-def _virtual_body(*refs, n_prefetch, strip_axis, out_rows, OH, OW, stride,
-                  kh, kw, rows_c, pool, OWo, activation, out_dtype,
-                  has_bias, has_bypass, bypass_first):
+def _virtual_body(*refs, n_prefetch, strip_axis, out_rows, OH, OW, kh, kw,
+                  rows_c, pool, OWo, activation, out_dtype, has_bias,
+                  has_bypass, bypass_first):
     refs = list(refs)
     rs_ref = refs.pop(0) if n_prefetch else None
     x_ref = refs.pop(0)
@@ -182,19 +194,18 @@ def _virtual_body(*refs, n_prefetch, strip_axis, out_rows, OH, OW, stride,
     bias_ref = refs.pop(0) if has_bias else None
     byp_ref = refs.pop(0) if has_bypass else None
     o_ref = refs.pop(0)
+    pool_ref = refs.pop(0) if pool is not None else None
 
     s = pl.program_id(strip_axis)
-    in_rows = (rows_c - 1) * stride + kh
     if rs_ref is not None:                     # scalar-prefetched offsets
         r0 = rs_ref[s]
-    else:                                      # affine: s * out_rows * stride
-        r0 = pl.multiple_of(s * (out_rows * stride), stride)
-    # The zero-copy gather: slice this strip's input window out of the
-    # VMEM-resident padded maps — no HBM duplication ever existed.
-    x = x_ref[0, pl.ds(r0, in_rows), :, :]     # (in_rows, Wp, Cin)
-
+    else:                                      # affine: s * out_rows
+        r0 = s * out_rows
+    # The zero-copy gather: each tap reads this strip's input window
+    # straight out of the VMEM-resident padded maps — no HBM
+    # duplication ever existed.
     kpt = o_ref.shape[-1]
-    acc = _implicit_gemm(x, w_ref, rows_c, OW, stride, kh, kw, kpt)
+    acc = _implicit_gemm(x_ref, r0, w_ref, rows_c, OW, kh, kw, kpt)
     byp = byp_ref[0] if byp_ref is not None else None
     acc = _epilogue(acc, bias_ref, byp, activation, bypass_first)
 
@@ -210,36 +221,38 @@ def _virtual_body(*refs, n_prefetch, strip_axis, out_rows, OH, OW, stride,
     # before reducing: -inf for max, 0 for avg (avgpool2d_ref divides
     # by the fixed window^2, counting pad as zeros, so a zero identity
     # reproduces it exactly).
+    # Mosaic slices vectors at unit stride only, so the conv rows are
+    # staged in f32 VMEM scratch, framed by the pool's padding, and each
+    # window tap is read back with a strided ref load.  Strided loads
+    # take one 128-lane tile at a time: the scratch holds the kernel
+    # tile's channels in 128-lane chunks.
     pw, ps, pp, pop = pool
     SR = out_rows // ps
     ident = jnp.float32(0.0 if pop == "avg" else -jnp.inf)
     gr = (s * out_rows - pp
           + jax.lax.broadcasted_iota(jnp.int32, (rows_c, 1, 1), 0))
     acc = jnp.where((gr >= 0) & (gr < OH), acc, ident)
-    wpad_r = max(0, (OWo - 1) * ps + pw - OW - pp)
-    if pp or wpad_r:
-        acc = jnp.pad(acc, ((0, 0), (pp, wpad_r), (0, 0)),
-                      constant_values=ident)
-    pooled = None
-    for py in range(pw):
-        for px in range(pw):
-            tap = jax.lax.slice(
-                acc, (py, px, 0),
-                (py + (SR - 1) * ps + 1, px + (OWo - 1) * ps + 1, kpt),
-                (ps, ps, 1))
-            if pooled is None:
-                pooled = tap
-            elif pop == "avg":
-                pooled = pooled + tap
-            else:
-                pooled = jnp.maximum(pooled, tap)
-    if pop == "avg":
-        pooled = pooled / jnp.float32(pw * pw)
-    o_ref[0] = pooled.astype(out_dtype)
+    pool_ref[...] = jnp.full(pool_ref.shape, ident, jnp.float32)
+    for j in range(pool_ref.shape[0]):
+        c0, c1 = j * 128, min(kpt, (j + 1) * 128)
+        pool_ref[j, :, pl.ds(pp, OW), :c1 - c0] = acc[:, :, c0:c1]
+        pooled = None
+        for py in range(pw):
+            for px in range(pw):
+                tap = pool_ref[j, pl.ds(py, SR, ps), pl.ds(px, OWo, ps), :]
+                if pooled is None:
+                    pooled = tap
+                elif pop == "avg":
+                    pooled = pooled + tap
+                else:
+                    pooled = jnp.maximum(pooled, tap)
+        if pop == "avg":
+            pooled = pooled / jnp.float32(pw * pw)
+        o_ref[0, :, :, c0:c1] = pooled[:, :, :c1 - c0].astype(out_dtype)
 
 
 def conv2d_virtual_pallas(xp, w, *, out_rows: int, OH: int, OW: int,
-                          stride: int, kpt: int, n_strips: int, bias=None,
+                          kpt: int, n_strips: int, bias=None,
                           activation: str | None = None, bypass=None,
                           bypass_first: bool = False, out_dtype=None,
                           dataflow: Dataflow = Dataflow.MAPS_RESIDENT,
@@ -248,12 +261,13 @@ def conv2d_virtual_pallas(xp, w, *, out_rows: int, OH: int, OW: int,
                           interpret: bool | None = None) -> jax.Array:
     """Zero-copy row-strip conv: xp is the whole padded maps
     (B, Hp, Wp, Cin) — no strip duplication; strips are gathered
-    in-kernel.  bypass: (B, n_strips*out_rows, OW, Cout) or None (not
-    combinable with ``pool``).  pool: (window, stride, pad, op) max or
-    avg pool fused after the epilogue.  row_starts: optional (n_strips,) int32
-    per-strip *input* row offsets, scalar-prefetched so the gather
+    in-kernel.  w: (kh, kw, Cin, Cout), unit stride (ops.py folds a
+    stride into channels).  bypass: (B, n_strips*out_rows, OW, Cout) or
+    None (not combinable with ``pool``).  pool: (window, stride, pad,
+    op) max or avg pool fused after the epilogue.  row_starts: optional
+    (n_strips,) int32 per-strip *input* row offsets, scalar-prefetched so the gather
     address is known before the body runs — for input-side offset
-    tables an affine ``s * out_rows * stride`` cannot express (e.g.
+    tables an affine ``s * out_rows`` cannot express (e.g.
     irregular row subsampling).  Output strips stay uniform: strip s
     always writes output rows [s*SR, (s+1)*SR), and the pool row mask
     is likewise derived from s, so a custom table must keep that
@@ -280,9 +294,14 @@ def conv2d_virtual_pallas(xp, w, *, out_rows: int, OH: int, OW: int,
         rows_c = out_rows + pw - ps            # extra rows: overlapping windows
         SR = out_rows // ps
         OWo = pool_out(OW, pw, ps, pp)
-    in_rows = (rows_c - 1) * stride + kh
-    assert (NS - 1) * out_rows * stride + in_rows <= Hp, \
+        wpool = max(pp + OW, (OWo - 1) * ps + pw)
+    scratch = []
+    if pool is not None:
+        scratch.append(vmem_scratch((-(-kpt // 128), rows_c, wpool, 128),
+                                    jnp.float32))
+    assert (NS - 1) * out_rows + rows_c - 1 + kh <= Hp, \
         "padded maps too short for the strip table"
+    assert Wp >= compute_cols(OW) + kw - 1, (Wp, OW, kw)
 
     if dataflow is Dataflow.WEIGHTS_RESIDENT:
         grid = (NK, B, NS)                   # weight tile resident (Mloop)
@@ -301,10 +320,6 @@ def conv2d_virtual_pallas(xp, w, *, out_rows: int, OH: int, OW: int,
 
     n_prefetch = 0
     if row_starts is not None:
-        if pltpu is None:
-            raise RuntimeError("row_starts requires the Pallas TPU "
-                               "backend (PrefetchScalarGridSpec); it is "
-                               "unavailable in this jax install")
         n_prefetch = 1
         # Index maps receive the prefetch ref as a trailing arg.
         wrap = lambda f: (lambda *a: f(*a[:3]))
@@ -327,22 +342,22 @@ def conv2d_virtual_pallas(xp, w, *, out_rows: int, OH: int, OW: int,
 
     body = functools.partial(
         _virtual_body, n_prefetch=n_prefetch, strip_axis=strip_axis,
-        out_rows=out_rows, OH=OH, OW=OW, stride=stride, kh=kh, kw=kw,
+        out_rows=out_rows, OH=OH, OW=OW, kh=kh, kw=kw,
         rows_c=rows_c, pool=pool, OWo=OWo, activation=activation,
         out_dtype=out_dtype, has_bias=has_bias, has_bypass=has_bypass,
         bypass_first=bypass_first)
     # All three grid dims write disjoint output blocks with no carried
     # state — parallel semantics everywhere (Mosaic double-buffers).
-    params = compiler_params(("parallel",) * 3, interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
+    params = compiler_params(("parallel",) * 3)
     if n_prefetch:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-            out_specs=out_spec)
+            out_specs=out_spec, scratch_shapes=scratch)
         return pl.pallas_call(body, grid_spec=grid_spec,
                               out_shape=out_shape, interpret=interpret,
-                              **kwargs)(row_starts.astype(jnp.int32),
-                                        *operands)
+                              compiler_params=params)(
+                                  row_starts.astype(jnp.int32), *operands)
     return pl.pallas_call(body, grid=grid, in_specs=in_specs,
                           out_specs=out_spec, out_shape=out_shape,
-                          interpret=interpret, **kwargs)(*operands)
+                          scratch_shapes=scratch, interpret=interpret,
+                          compiler_params=params)(*operands)

@@ -26,24 +26,26 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..common import resolve_impl
 from ...core.dataflow import Dataflow, choose_conv_dataflow
 from ...core.hw import TPU_V5E, HardwareModel
 from ...core.ir import pool_out
 from ...core.tiling import ConvTiling, select_conv_row_strips
-from .kernel import conv2d_strips_pallas, conv2d_virtual_pallas
+from .kernel import (compute_cols, conv2d_strips_pallas,
+                     conv2d_virtual_pallas)
 from .ref import avgpool2d_ref, conv2d_ref, maxpool2d_ref
 
 __all__ = ["conv2d"]
 
 
-def _materialize_strips(xp, n_strips, out_rows, in_rows, stride):
+def _materialize_strips(xp, n_strips, out_rows, in_rows):
     """Gather halo-augmented row strips into one HBM array:
     (B, Hp, Wp, C) -> (B*NS, in_rows, Wp, C).  This duplicates
     ``overlap_frac`` of the maps off-chip — the Snowflake baseline the
     zero-copy path exists to kill; kept for ``strip_storage=
     "materialized"`` and the strip-storage benchmark."""
     B, Hp, Wp, C = xp.shape
-    starts = jnp.arange(n_strips) * out_rows * stride
+    starts = jnp.arange(n_strips) * out_rows
     def one(start):
         return jax.lax.dynamic_slice(xp, (0, start, 0, 0),
                                      (B, in_rows, Wp, C))
@@ -105,8 +107,7 @@ def conv2d(x, w, *, stride: int = 1, pad: int = 0, bias=None,
         raise ValueError(f"strip_offsets must be affine|prefetch, "
                          f"got {strip_offsets!r}")
     pool = _norm_pool(fuse_pool)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl)
     if impl == "reference":
         out = conv2d_ref(x, w, stride=stride, pad=pad, bias=bias,
                          activation=activation, bypass=bypass,
@@ -122,9 +123,8 @@ def conv2d(x, w, *, stride: int = 1, pad: int = 0, bias=None,
     ct = tiling if tiling is not None else select_conv_row_strips(
         H, W, Cin, Cout, kh, kw, stride, pad, x.dtype.itemsize, hw, batch=B)
     storage = ct.strip_storage if strip_storage == "auto" else strip_storage
-    out_rows, kpt = ct.out_rows, ct.kernels_per_tile
-    while Cout % kpt != 0:
-        kpt -= 1
+    out_rows = ct.out_rows
+    kpt = _lane_kernel_tile(ct.kernels_per_tile, Cout)
 
     if storage != "virtual":
         # Paper-faithful fallback: conv via materialized strips, pool
@@ -167,11 +167,9 @@ def conv2d(x, w, *, stride: int = 1, pad: int = 0, bias=None,
                 f"{OH}x{OW} conv output")
         n_strips = math.ceil(OHo / SR)
         top_pad = pad + pp * stride      # phantom rows for the pool's top pad
-    in_rows = (rows_c - 1) * stride + kh
-    Hp_needed = (n_strips - 1) * out_rows * stride + in_rows
-    xp = jnp.pad(x, ((0, 0),
-                     (top_pad, max(0, Hp_needed - H - top_pad)),
-                     (pad, pad), (0, 0)))
+    xs, ws = _fold_stride(x, w, stride, top=top_pad, left=pad,
+                          rows=(n_strips - 1) * out_rows + rows_c,
+                          OW=OW)
 
     if dataflow is None:
         by = x.dtype.itemsize
@@ -189,16 +187,59 @@ def conv2d(x, w, *, stride: int = 1, pad: int = 0, bias=None,
 
     row_starts = None
     if strip_offsets == "prefetch":
-        row_starts = jnp.arange(n_strips, dtype=jnp.int32) * (
-            out_rows * stride)
+        row_starts = jnp.arange(n_strips, dtype=jnp.int32) * out_rows
 
     out = conv2d_virtual_pallas(
-        xp, w, out_rows=out_rows, OH=OH, OW=OW, stride=stride, kpt=kpt,
+        xs, ws, out_rows=out_rows, OH=OH, OW=OW, kpt=kpt,
         n_strips=n_strips, bias=bias, activation=activation, bypass=byp,
         bypass_first=bypass_first, out_dtype=out_dtype or x.dtype,
         dataflow=dataflow, pool=pool, row_starts=row_starts,
         interpret=interpret)
     return out[:, :OHo]
+
+
+def _lane_kernel_tile(kpt: int, Cout: int) -> int:
+    """The kernel-tile width the kernel runs: the widest divisor of Cout
+    no wider than the schedule's ``kpt`` that fills whole 128-lane
+    vregs, or all of Cout when none does (the TPU lowering accepts a
+    channel block only at a lane multiple or the full extent)."""
+    for t in range(kpt - kpt % 128, 0, -128):
+        if Cout % t == 0:
+            return t
+    return Cout
+
+
+def _fold_stride(x, w, stride, *, top, left, rows, OW):
+    """Pad the maps and fold the conv stride into channels
+    (space-to-depth), so the kernel runs a unit-stride conv.
+
+    x: (B, H, W, C); w: (kh, kw, C, Co).  ``top`` / ``left`` rows and
+    columns of zeros go before the maps; ``rows`` output rows (counted
+    from the first padded one) must be computable, and each of them
+    ``compute_cols(OW)`` sublane-aligned columns.  Stride-s row r of the
+    padded maps becomes row r // s, channel block (r % s, col % s) of
+    the folded maps; a k x k kernel becomes ceil(k/s) x ceil(k/s) taps
+    over s*s*C channels, zero where k falls short of a multiple of s.
+    Returns the folded maps (B, Hs, Ws, s*s*C) and weights."""
+    B, H, W, C = x.shape
+    kh, kw, _, Co = w.shape
+    qh, qw = -(-kh // stride), -(-kw // stride)
+    Hs = rows - 1 + qh
+    Ws = compute_cols(OW) + qw - 1
+    # lax.pad takes negative high padding: rows no output reads are cut.
+    zero = jnp.zeros((), x.dtype)
+    xp = jax.lax.pad(x, zero, ((0, 0, 0), (top, Hs * stride - H - top, 0),
+                               (left, Ws * stride - W - left, 0),
+                               (0, 0, 0)))
+    if stride == 1:
+        return xp, w
+    xs = xp.reshape(B, Hs, stride, Ws, stride, C)
+    xs = xs.transpose(0, 1, 3, 2, 4, 5).reshape(B, Hs, Ws, stride ** 2 * C)
+    wp = jnp.pad(w, ((0, qh * stride - kh), (0, qw * stride - kw),
+                     (0, 0), (0, 0)))
+    ws = wp.reshape(qh, stride, qw, stride, C, Co)
+    ws = ws.transpose(0, 2, 1, 3, 4, 5).reshape(qh, qw, stride ** 2 * C, Co)
+    return xs, ws
 
 
 def _conv2d_materialized(x, w, *, stride, pad, bias, activation, bypass,
@@ -207,7 +248,6 @@ def _conv2d_materialized(x, w, *, stride, pad, bias, activation, bypass,
     """The paper's scheme: halo-augmented strips duplicated in HBM."""
     B, H, W, Cin = x.shape
     kh, kw, _, Cout = w.shape
-    in_rows = (out_rows - 1) * stride + kh   # full window (pad supplies halo)
     n_strips = math.ceil(OH / out_rows)
 
     if dataflow is None:
@@ -218,11 +258,11 @@ def _conv2d_materialized(x, w, *, stride, pad, bias, activation, bypass,
             n_map_tiles=B * n_strips, n_kernel_tiles=Cout // kpt,
             overlap_frac=ct.overlap_frac, strip_storage="materialized")
 
-    # Pad: spatial conv padding + bottom rows so every strip is full.
-    Hp_needed = (n_strips - 1) * out_rows * stride + in_rows
-    xp = jnp.pad(x, ((0, 0), (pad, max(pad, Hp_needed - H - pad)),
-                     (pad, pad), (0, 0)))
-    strips = _materialize_strips(xp, n_strips, out_rows, in_rows, stride)
+    # Pad (+ bottom rows so every strip is full) and fold the stride.
+    xs, ws = _fold_stride(x, w, stride, top=pad, left=pad,
+                          rows=n_strips * out_rows, OW=OW)
+    in_rows = out_rows - 1 + ws.shape[0]     # full window (pad supplies halo)
+    strips = _materialize_strips(xs, n_strips, out_rows, in_rows)
 
     byp = None
     if bypass is not None:
@@ -232,7 +272,7 @@ def _conv2d_materialized(x, w, *, stride, pad, bias, activation, bypass,
         byp = byp.reshape(B * n_strips, out_rows, OW, Cout)
 
     out = conv2d_strips_pallas(
-        strips, w, out_rows=out_rows, OW=OW, stride=stride, kpt=kpt,
+        strips, ws, out_rows=out_rows, OW=OW, kpt=kpt,
         bias=bias, activation=activation, bypass=byp,
         bypass_first=bypass_first, out_dtype=out_dtype or x.dtype,
         dataflow=dataflow, interpret=interpret)

@@ -25,58 +25,79 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import compiler_params, default_interpret, vmem_scratch
+from ..common import compiler_params, default_interpret, pltpu, vmem_scratch
 
 __all__ = ["decode_attention_pallas", "paged_decode_attention_pallas"]
 
 NEG_INF = -1e30
 
 
+def _online_softmax_step(q, k, v, valid, m_ref, l_ref, acc_ref, scale):
+    """Fold one kv block into the running softmax state.  q: (1, D);
+    k, v: (bkv, D) f32; valid: (1, bkv) row mask."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+    alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
+    p = jnp.exp(s - m_new[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
+        p.sum(-1, keepdims=True), l_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _emit(o_ref, l_ref, acc_ref):
+    l = jnp.maximum(l_ref[:, :1], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
 def _body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-          scale, bkv):
+          scale, bkv, Hq):
     kb = pl.program_id(1)
     nkv = pl.num_programs(1)
 
     @pl.when(kb == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0) // Hq]     # scalar-prefetched (SMEM)
     sk0 = kb * bkv
 
     @pl.when(sk0 < kv_len)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)            # (1, D)
-        k = k_ref[0].astype(jnp.float32)            # (bkv, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
         ki = sk0 + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
-        s = jnp.where(ki < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-        p = jnp.exp(s - m_new[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
-            p.sum(-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _online_softmax_step(q_ref[0].astype(jnp.float32),
+                             k_ref[0].astype(jnp.float32),
+                             v_ref[0].astype(jnp.float32), ki < kv_len,
+                             m_ref, l_ref, acc_ref, scale)
 
     @pl.when(kb == nkv - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        _emit(o_ref, l_ref, acc_ref)
+
+
+def _state_scratch(D):
+    return [vmem_scratch((1, 128), jnp.float32),
+            vmem_scratch((1, 128), jnp.float32),
+            vmem_scratch((1, D), jnp.float32)]
 
 
 def decode_attention_pallas(q, k, v, kv_len, *, scale: float,
                             block_kv: int = 1024,
                             interpret: bool | None = None) -> jax.Array:
-    """q: (B, Hq, D); k, v: (B, Hkv, S, D); kv_len: (B,) int32."""
+    """q: (B, Hq, D); k, v: (B, Hkv, S, D); kv_len: (B,) int32, handed
+    to the kernel as a scalar-prefetch operand (it lives in SMEM)."""
     if interpret is None:
         interpret = default_interpret()
     B, Hq, D = q.shape
@@ -88,88 +109,78 @@ def decode_attention_pallas(q, k, v, kv_len, *, scale: float,
     qf = q.reshape(B * Hq, 1, D)
     kf = k.reshape(B * Hkv, S, D)
     vf = v.reshape(B * Hkv, S, D)
-    lenf = kv_len.astype(jnp.int32)
 
-    def kv_map(h, kb):
+    def kv_map(h, kb, lens):
         return ((h // Hq) * Hkv + (h % Hq) // group, kb, 0)
 
-    grid = (B * Hq, S // bkv)
-    body = functools.partial(_body, scale=scale, bkv=bkv)
-    params = compiler_params(("parallel", "arbitrary"), interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
-    out = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda h, kb: (h // Hq,)),
-                  pl.BlockSpec((1, 1, D), lambda h, kb: (h, 0, 0)),
-                  pl.BlockSpec((1, bkv, D), kv_map),
+    row = pl.BlockSpec((1, 1, D), lambda h, kb, lens: (h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B * Hq, S // bkv),
+        in_specs=[row, pl.BlockSpec((1, bkv, D), kv_map),
                   pl.BlockSpec((1, bkv, D), kv_map)],
-        out_specs=pl.BlockSpec((1, 1, D), lambda h, kb: (h, 0, 0)),
+        out_specs=row, scratch_shapes=_state_scratch(D))
+    out = pl.pallas_call(
+        functools.partial(_body, scale=scale, bkv=bkv, Hq=Hq),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, D), q.dtype),
-        scratch_shapes=[vmem_scratch((1, 128), jnp.float32),
-                        vmem_scratch((1, 128), jnp.float32),
-                        vmem_scratch((1, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
-    )(lenf, qf, kf, vf)
+        compiler_params=compiler_params(("parallel", "arbitrary")),
+    )(kv_len.astype(jnp.int32), qf, kf, vf)
     return out.reshape(B, Hq, D)
 
 
-def _paged_body(len_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                o_ref, m_ref, l_ref, acc_ref, *, scale, page_size, Hq, Hkv):
+def _paged_body(len_ref, pt_ref, *refs, scale, page_size, Hq, Hkv, quant):
     """One (sequence*head, page) grid step of paged decode attention.
 
-    The kv block IS the page: the page table block (1, 1) names which
-    pool page this step reads, and the page's rows are loaded with a
-    dynamic ``pl.ds`` gather from the whole-pool ref — the page id is a
-    runtime value, so it cannot appear in a BlockSpec index map without
-    TPU-only scalar prefetch; keeping the gather in the body keeps the
-    kernel portable to interpret mode.  Quantized pools (ks/vs scale
-    refs present) are dequantized per page right after the load."""
+    The kv block IS the page: the page table and the ring extents are
+    scalar-prefetched into SMEM, and the page this step reads is
+    gathered with a dynamic index into the whole-pool ref.  Quantized
+    pools carry per-page dequant scales, also in SMEM, applied right
+    after the load."""
+    if quant:
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     h = pl.program_id(0)
     kb = pl.program_id(1)
     nkv = pl.num_programs(1)
+    b = h // Hq
     kvh = (h % Hq) // (Hq // Hkv)
 
     @pl.when(kb == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[b]
     sk0 = kb * page_size
 
     @pl.when(sk0 < kv_len)
     def _compute():
-        page = pt_ref[0, 0]
-        idx = (pl.ds(page, 1), slice(None), pl.ds(kvh, 1), slice(None))
-        k = pl.load(k_ref, idx)[0, :, 0, :].astype(jnp.float32)  # (pg, D)
-        v = pl.load(v_ref, idx)[0, :, 0, :].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * pl.load(ks_ref, (pl.ds(page, 1),))[0]
-            v = v * pl.load(vs_ref, (pl.ds(page, 1),))[0]
-        q = q_ref[0].astype(jnp.float32)                         # (1, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        page = pt_ref[b, kb]
+        k = _head_rows(k_ref[page], kvh, Hkv)                    # (pg, D)
+        v = _head_rows(v_ref[page], kvh, Hkv)
+        if quant:
+            k = k * ks_ref[page]
+            v = v * vs_ref[page]
         ki = sk0 + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        s = jnp.where(ki < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-        p = jnp.exp(s - m_new[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
-            p.sum(-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _online_softmax_step(q_ref[0].astype(jnp.float32), k, v,
+                             ki < kv_len, m_ref, l_ref, acc_ref, scale)
 
     @pl.when(kb == nkv - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        _emit(o_ref, l_ref, acc_ref)
+
+
+def _head_rows(page, kvh, Hkv):
+    """Rows of KV head ``kvh`` (a runtime index) from one loaded page
+    (page_size, Hkv, D), as f32 (page_size, D).  Mosaic cannot index the
+    sublane (head) dim at a runtime offset, so each head is sliced at a
+    static one and the runtime index selects among them."""
+    page = page.astype(jnp.float32)
+    rows = page[:, 0, :]
+    for j in range(1, Hkv):
+        rows = jnp.where(kvh == j, page[:, j, :], rows)
+    return rows
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
@@ -181,10 +192,10 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
     ring extents.  k_scale / v_scale: (n_pages,) float32 per-page
     dequant scales for int8 pools, or None for float pools.
 
-    Grid (B * Hq, pages_per_slot) — the page table is blocked (1, 1)
-    per grid step and the pools ride along whole (their index map is
-    constant) because the page id is runtime data.  block_kv ==
-    page_size by construction (core/tiling.py pins it)."""
+    Grid (B * Hq, pages_per_slot).  kv_len, the page table and the
+    scales are scalar-prefetch operands; the pools ride along whole
+    (their index map is constant), so the pool must fit VMEM.
+    block_kv == page_size by construction (core/tiling.py pins it)."""
     if interpret is None:
         interpret = default_interpret()
     B, Hq, D = q.shape
@@ -192,43 +203,24 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
     pages_per_slot = page_table.shape[1]
     quant = k_scale is not None
 
-    qf = q.reshape(B * Hq, 1, D)
-    lenf = kv_len.astype(jnp.int32)
-
-    whole_pool = pl.BlockSpec((n_pages, page_size, Hkv, D),
-                              lambda h, kb: (0, 0, 0, 0))
-    in_specs = [pl.BlockSpec((1,), lambda h, kb: (h // Hq,)),
-                pl.BlockSpec((1, 1), lambda h, kb: (h // Hq, kb)),
-                pl.BlockSpec((1, 1, D), lambda h, kb: (h, 0, 0)),
-                whole_pool, whole_pool]
-    args = [lenf, page_table.astype(jnp.int32), qf, k_pages, v_pages]
+    prefetch = [kv_len.astype(jnp.int32), page_table.astype(jnp.int32)]
     if quant:
-        in_specs += [pl.BlockSpec((n_pages,), lambda h, kb: (0,))] * 2
-        args += [k_scale, v_scale]
-
-    def body(*refs):
-        if quant:
-            len_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:7]
-            rest = refs[7:]
-        else:
-            len_ref, pt_ref, q_ref, k_ref, v_ref = refs[:5]
-            ks_ref = vs_ref = None
-            rest = refs[5:]
-        _paged_body(len_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                    *rest, scale=scale, page_size=page_size, Hq=Hq, Hkv=Hkv)
-
-    params = compiler_params(("parallel", "arbitrary"), interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
+        prefetch += [k_scale.astype(jnp.float32),
+                     v_scale.astype(jnp.float32)]
+    n_pf = len(prefetch)
+    whole_pool = pl.BlockSpec((n_pages, page_size, Hkv, D),
+                              lambda h, kb, *_: (0, 0, 0, 0))
+    row = pl.BlockSpec((1, 1, D), lambda h, kb, *_: (h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pf, grid=(B * Hq, pages_per_slot),
+        in_specs=[row, whole_pool, whole_pool], out_specs=row,
+        scratch_shapes=_state_scratch(D))
     out = pl.pallas_call(
-        body,
-        grid=(B * Hq, pages_per_slot),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), lambda h, kb: (h, 0, 0)),
+        functools.partial(_paged_body, scale=scale, page_size=page_size,
+                          Hq=Hq, Hkv=Hkv, quant=quant),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, D), q.dtype),
-        scratch_shapes=[vmem_scratch((1, 128), jnp.float32),
-                        vmem_scratch((1, 128), jnp.float32),
-                        vmem_scratch((1, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
-    )(*args)
+        compiler_params=compiler_params(("parallel", "arbitrary")),
+    )(*prefetch, q.reshape(B * Hq, 1, D), k_pages, v_pages)
     return out.reshape(B, Hq, D)

@@ -17,6 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..common import resolve_impl
 from ...core.hw import TPU_V5E, HardwareModel
 from .kernel import decode_attention_pallas, paged_decode_attention_pallas
 from .ref import decode_attention_ref
@@ -73,8 +74,7 @@ def decode_attention(q, k, v, *, kv_len=None, scale: float | None = None,
     scale = scale if scale is not None else D ** -0.5
     if kv_len is None:
         kv_len = jnp.full((B,), S, jnp.int32)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl)
     if impl == "reference":
         return decode_attention_ref(q, k, v, kv_len=kv_len, scale=scale)
     if block_kv is None:
@@ -140,8 +140,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, *, kv_len,
     if kv_len is None:
         kv_len = jnp.full((B,), page_table.shape[1] * k_pages.shape[1],
                           jnp.int32)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl)
     if impl == "reference":
         k = gather_pages(k_pages, page_table, k_scale)
         v = gather_pages(v_pages, page_table, v_scale)

@@ -8,8 +8,9 @@
   while streaming kv blocks (KV indexed through the GQA head map, as in
   the forward kernel).
 
-Both recompute p = exp(q k^T * scale - lse) from the forward's saved
-logsumexp; ``delta = rowsum(dO * O)`` is precomputed in ops.py.
+Both recompute p^T = exp(k q^T * scale - lse) from the forward's saved
+logsumexp, transposed so the per-query rows (lse and ``delta =
+rowsum(dO * O)``, precomputed below) broadcast along lanes.
 Masking (causal / window / kv_len) matches the forward kernel.
 """
 from __future__ import annotations
@@ -27,17 +28,37 @@ __all__ = ["flash_attention_bwd_pallas"]
 NEG_INF = -1e30
 
 
-def _mask(s, sq0, sk0, bq, bkv, causal, window, kv_len):
-    qi = sq0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-    ki = sk0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-    ok = jnp.ones((bq, bkv), jnp.bool_)
+def _scores_t(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *, scale,
+              causal, window, kv_len, sq0, sk0, bq, bkv):
+    """Recompute the transposed probabilities and score gradients.
+
+    Everything is laid out (bkv, bq): kv rows on sublanes, queries on
+    lanes, so the saved per-query logsumexp and delta broadcast as the
+    lane-dense (1, bq) rows they are stored as.  Returns (q, do, p^T,
+    ds^T) with q/do as f32 (bq, D)."""
+    q = q_ref[0].astype(jnp.float32)                     # (bq, D)
+    k = k_ref[0].astype(jnp.float32)                     # (bkv, D)
+    v = v_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)                   # (bq, D)
+    lse = lse_ref[0]                                     # (1, bq)
+    delta = dl_ref[0]                                    # (1, bq)
+    st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * scale
+    ki = sk0 + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 0)
+    qi = sq0 + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 1)
+    ok = jnp.ones((bkv, bq), jnp.bool_)
     if causal:
         ok &= ki <= qi
     if window is not None:
         ok &= ki > qi - window
     if kv_len is not None:
         ok &= ki < kv_len
-    return jnp.where(ok, s, NEG_INF)
+    st = jnp.where(ok, st, NEG_INF)
+    pt = jnp.exp(st - lse)                               # (bkv, bq)
+    dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    dst = pt * (dpt - delta) * scale
+    return q, do, pt, dst
 
 
 def _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -63,24 +84,15 @@ def _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     @pl.when(run)
     def _acc():
-        q = q_ref[0].astype(jnp.float32)                 # (bq, D)
-        k = k_ref[0].astype(jnp.float32)                 # (bkv, D)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)               # (bq, D)
-        lse = lse_ref[0].astype(jnp.float32)             # (bq,)
-        delta = dl_ref[0].astype(jnp.float32)            # (bq,)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask(s, sq0, sk0, bq, bkv, causal, window, kv_len)
-        p = jnp.exp(s - lse[:, None])                    # (bq, bkv)
+        q, do, pt, dst = _scores_t(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, scale=scale,
+            causal=causal, window=window, kv_len=kv_len, sq0=sq0, sk0=sk0,
+            bq=bq, bkv=bkv)
         dva_ref[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            pt, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # p^T dO
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
         dka_ref[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # ds^T q
 
     @pl.when(inner == n_inner - 1)
@@ -109,22 +121,13 @@ def _dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     @pl.when(run)
     def _acc():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0].astype(jnp.float32)
-        delta = dl_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask(s, sq0, sk0, bq, bkv, causal, window, kv_len)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        _, _, _, dst = _scores_t(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, scale=scale,
+            causal=causal, window=window, kv_len=kv_len, sq0=sq0, sk0=sk0,
+            bq=bq, bkv=bkv)
         dqa_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dst, k_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # ds k
 
     @pl.when(kb == nkv - 1)
     def _emit():
@@ -152,22 +155,20 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, scale: float,
     kf = k.reshape(B * Hkv, Skv, D)
     vf = v.reshape(B * Hkv, Skv, D)
     dof = do.reshape(B * Hq, Sq, D)
-    lsef = lse.reshape(B * Hq, Sq)
+    # Per-query rows stay lane-dense: (B*Hq, 1, Sq), blocked (1, 1, bq).
+    lsef = lse.astype(jnp.float32).reshape(B * Hq, 1, Sq)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(B * Hq, Sq)
-
-    params = compiler_params(("parallel", "arbitrary", "arbitrary"),
-                             interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
+                    axis=-1).reshape(B * Hq, 1, Sq)
+    params = compiler_params(("parallel", "arbitrary", "arbitrary"))
 
     # -- dk / dv: per kv head, inner axis sweeps (group, q block) -------------
     def qhead(h, kb, inner):
         return ((h // Hkv) * Hq + (h % Hkv) * G + inner // nq,
                 inner % nq, 0)
 
-    def qhead2(h, kb, inner):
+    def qrow(h, kb, inner):
         hq, qb, _ = qhead(h, kb, inner)
-        return (hq, qb)
+        return (hq, 0, qb)
 
     body = functools.partial(_dkv_body, scale=scale, causal=causal,
                              window=window, kv_len=kv_len, bq=bq,
@@ -180,8 +181,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, scale: float,
             pl.BlockSpec((1, bkv, D), lambda h, kb, i: (h, kb, 0)),
             pl.BlockSpec((1, bkv, D), lambda h, kb, i: (h, kb, 0)),
             pl.BlockSpec((1, bq, D), qhead),
-            pl.BlockSpec((1, bq), qhead2),
-            pl.BlockSpec((1, bq), qhead2),
+            pl.BlockSpec((1, 1, bq), qrow),
+            pl.BlockSpec((1, 1, bq), qrow),
         ],
         out_specs=[pl.BlockSpec((1, bkv, D), lambda h, kb, i: (h, kb, 0)),
                    pl.BlockSpec((1, bkv, D), lambda h, kb, i: (h, kb, 0))],
@@ -190,7 +191,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, scale: float,
         scratch_shapes=[vmem_scratch((bkv, D), jnp.float32),
                         vmem_scratch((bkv, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(qf, kf, vf, dof, lsef, delta)
 
     # -- dq: per q head, kv innermost ------------------------------------------
@@ -207,14 +208,14 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, *, scale: float,
             pl.BlockSpec((1, bkv, D), kvmap),
             pl.BlockSpec((1, bkv, D), kvmap),
             pl.BlockSpec((1, bq, D), lambda h, qb, kb: (h, qb, 0)),
-            pl.BlockSpec((1, bq), lambda h, qb, kb: (h, qb)),
-            pl.BlockSpec((1, bq), lambda h, qb, kb: (h, qb)),
+            pl.BlockSpec((1, 1, bq), lambda h, qb, kb: (h, 0, qb)),
+            pl.BlockSpec((1, 1, bq), lambda h, qb, kb: (h, 0, qb)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda h, qb, kb: (h, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
         scratch_shapes=[vmem_scratch((bq, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(qf, kf, vf, dof, lsef, delta)
 
     return (dq.reshape(B, Hq, Sq, D), dk.reshape(B, Hkv, Skv, D),

@@ -80,9 +80,11 @@ def _body(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(kb == nkv - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[:, 0] + jnp.log(l[:, 0])).astype(jnp.float32)
+        l = jnp.maximum(l_ref[...], 1e-30)               # (bq, 128)
+        o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
+        # m and l are lane-replicated columns; one transpose turns the
+        # logsumexp into the lane-dense (1, bq) row the lse block holds.
+        lse_ref[0] = jnp.transpose(m_ref[...] + jnp.log(l))[:1]
 
 
 def flash_attention_pallas(q, k, v, *, scale: float, causal: bool,
@@ -115,25 +117,23 @@ def flash_attention_pallas(q, k, v, *, scale: float, causal: bool,
     k_spec = pl.BlockSpec((1, bkv, D), kv_head)
     v_spec = pl.BlockSpec((1, bkv, D), kv_head)
     o_spec = pl.BlockSpec((1, bq, D), lambda h, qb, kb: (h, qb, 0))
-    lse_spec = pl.BlockSpec((1, bq), lambda h, qb, kb: (h, qb))
+    lse_spec = pl.BlockSpec((1, 1, bq), lambda h, qb, kb: (h, 0, qb))
 
     body = functools.partial(_body, scale=scale, causal=causal,
                              window=window, bq=bq, bkv=bkv, kv_len=kv_len)
-    params = compiler_params(("parallel", "arbitrary", "arbitrary"),
-                             interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
     out, lse = pl.pallas_call(
         body,
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec],
         out_specs=[o_spec, lse_spec],
         out_shape=[jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
-                   jax.ShapeDtypeStruct((B * Hq, Sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((B * Hq, 1, Sq), jnp.float32)],
         scratch_shapes=[vmem_scratch((bq, 128), jnp.float32),
                         vmem_scratch((bq, 128), jnp.float32),
                         vmem_scratch((bq, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(("parallel", "arbitrary",
+                                         "arbitrary")),
     )(qf, kf, vf)
     out = out.reshape(B, Hq, Sq, D)
     if return_lse:

@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..common import default_interpret
+from ..common import resolve_impl
 from ...core.hw import TPU_V5E, HardwareModel
 from .bwd_kernel import flash_attention_bwd_pallas
 from .kernel import flash_attention_pallas
@@ -80,11 +80,9 @@ def flash_attention(q, k, v, *, scale: float | None = None,
                     (forward-only use is the serving path);
       "auto"      — pallas on TPU else reference.
     """
-    if impl == "auto":
-        # trainable = fwd + bwd Pallas kernels; fwd is identical, so this
-        # is safe for inference too
-        impl = ("pallas_trainable" if jax.default_backend() == "tpu"
-                else "reference")
+    # "auto" on the chip: fwd + bwd Pallas kernels; the fwd is
+    # identical, so this is safe for inference too.
+    impl = resolve_impl(impl, tpu="pallas_trainable")
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     if impl == "reference":

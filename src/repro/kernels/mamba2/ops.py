@@ -1,9 +1,9 @@
 """Public Mamba2 scan wrapper: dispatch, D-skip fusion, decode step."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..common import chunk_and_length, resolve_impl
 from .kernel import mamba2_scan_pallas
 from .ref import mamba2_scan_chunked, mamba2_scan_ref
 
@@ -14,8 +14,7 @@ def mamba2_scan(x, dt, A, B, C, *, D_skip=None, h0=None,
                 return_state: bool = False, impl: str = "auto",
                 chunk: int = 256, interpret: bool | None = None):
     """Selective state-space scan.  Shapes as in ref.py."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl)
     if impl == "sequential":
         return mamba2_scan_ref(x, dt, A, B, C, D_skip=D_skip, h0=h0,
                                return_state=return_state)
@@ -25,12 +24,16 @@ def mamba2_scan(x, dt, A, B, C, *, D_skip=None, h0=None,
         return mamba2_scan_chunked(x, dt, A, B, C, D_skip=D_skip, h0=h0,
                                    return_state=return_state,
                                    chunk=min(chunk, 256))
+    # Pad the sequence to whole chunks with dt = 0 steps, which leave the
+    # state untouched and add nothing to earlier outputs.
     L = x.shape[1]
-    ch = min(chunk, L)
-    while L % ch != 0:
-        ch //= 2
-    y, h_fin = mamba2_scan_pallas(x, dt, A, B, C, h0=h0, chunk=max(ch, 1),
-                                  interpret=interpret)
+    Q, Lp = chunk_and_length(L, chunk)
+    tail = ((0, 0), (0, Lp - L))
+    y, h_fin = mamba2_scan_pallas(
+        jnp.pad(x, tail + ((0, 0), (0, 0))), jnp.pad(dt, tail + ((0, 0),)),
+        A, jnp.pad(B, tail + ((0, 0),)), jnp.pad(C, tail + ((0, 0),)),
+        h0=h0, chunk=Q, interpret=interpret)
+    y = y[:, :L]
     if D_skip is not None:
         y = y + (D_skip.astype(jnp.float32)[None, None, :, None]
                  * x.astype(jnp.float32)).astype(y.dtype)

@@ -18,7 +18,6 @@ Inputs must be pre-padded to block multiples (ops.py does this).
 from __future__ import annotations
 
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -154,10 +153,6 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
     if has_bypass:
         operands.append(bypass)
 
-    params = compiler_params(semantics, interpret)
-    kwargs = {}
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         body,
         grid=grid,
@@ -166,5 +161,5 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(semantics),
     )(*operands)

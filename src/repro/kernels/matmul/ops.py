@@ -7,12 +7,10 @@ e.g. inside the CPU dry-run where Mosaic is unavailable).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from ..common import default_interpret, pad_to, unpad
+from ..common import pad_to, resolve_impl, unpad
 from ...core.dataflow import Dataflow, choose_matmul_dataflow
 from ...core.hw import TPU_V5E, HardwareModel
 from .kernel import matmul_pallas
@@ -41,8 +39,7 @@ def matmul(a: jax.Array, b: jax.Array, *,
     a: (..., K); b: (K, N); bias: (N,); bypass: broadcastable to out.
     impl: "auto" | "pallas" | "reference".
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl)
     if impl == "reference":
         return matmul_ref(a, b, bias=bias, activation=activation,
                           bypass=bypass, out_dtype=out_dtype)

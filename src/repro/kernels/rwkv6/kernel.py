@@ -26,6 +26,9 @@ from ..common import compiler_params, default_interpret, vmem_scratch
 __all__ = ["wkv6_pallas"]
 
 
+_G = 8    # time steps per aligned row group (the f32 sublane tile)
+
+
 def _body(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
           s_ref, *, Q):
     c = pl.program_id(1)
@@ -35,19 +38,28 @@ def _body(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     def _init():
         s_ref[...] = s0_ref[0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                       # (D,)
+    u = u_ref[0].astype(jnp.float32)                       # (D, 1)
 
-    def step(t, S):
-        rt = r_ref[0, t].astype(jnp.float32)               # (D,)
-        kt = k_ref[0, t].astype(jnp.float32)
-        vt = v_ref[0, t].astype(jnp.float32)
-        wt = w_ref[0, t].astype(jnp.float32)
-        kv = kt[:, None] * vt[None, :]                     # (D, D)
-        y = jnp.einsum("i,ij->j", rt, S + u[:, None] * kv)
-        y_ref[0, t] = y.astype(y_ref.dtype)
-        return wt[:, None] * S + kv
+    def group(g, S):
+        # Load _G steps at a sublane-aligned offset; k, r and w enter
+        # the update as columns (the state's key axis is its sublane
+        # axis), so their tiles are transposed once per group.
+        t0 = pl.multiple_of(g * _G, _G)
+        rows = pl.ds(t0, _G)
+        rT = r_ref[0, rows].astype(jnp.float32).T          # (D, G)
+        kT = k_ref[0, rows].astype(jnp.float32).T
+        wT = w_ref[0, rows].astype(jnp.float32).T
+        v = v_ref[0, rows].astype(jnp.float32)             # (G, D)
+        ys = []
+        for i in range(_G):
+            kv = kT[:, i:i + 1] * v[i:i + 1]               # (D, D)
+            ys.append(jnp.sum(rT[:, i:i + 1] * (S + u * kv), axis=0,
+                              keepdims=True))              # (1, D)
+            S = wT[:, i:i + 1] * S + kv
+        y_ref[0, rows] = jnp.concatenate(ys, axis=0).astype(y_ref.dtype)
+        return S
 
-    S = jax.lax.fori_loop(0, Q, step, s_ref[...])
+    S = jax.lax.fori_loop(0, Q // _G, group, s_ref[...])
     s_ref[...] = S
 
     @pl.when(c == nc - 1)
@@ -57,12 +69,14 @@ def _body(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 def wkv6_pallas(r, k, v, w, u, *, s0=None, chunk: int = 128,
                 interpret: bool | None = None):
-    """r,k,v,w: (B, L, H, D); u: (H, D).  Returns (y, final_state)."""
+    """r,k,v,w: (B, L, H, D); u: (H, D).  L must be a multiple of the
+    chunk, and the chunk of 8 (ops.py pads).  Returns (y,
+    final_state)."""
     if interpret is None:
         interpret = default_interpret()
     B, L, H, D = r.shape
     Q = min(chunk, L)
-    assert L % Q == 0
+    assert L % Q == 0 and Q % _G == 0, (L, Q)
 
     def fold(a):
         return jnp.moveaxis(a, 2, 1).reshape(B * H, L, D)
@@ -71,24 +85,20 @@ def wkv6_pallas(r, k, v, w, u, *, s0=None, chunk: int = 128,
     s0f = (s0.reshape(B * H, D, D) if s0 is not None
            else jnp.zeros((B * H, D, D), jnp.float32))
 
-    grid = (B * H, L // Q)
-    body = functools.partial(_body, Q=Q)
-    params = compiler_params(("parallel", "arbitrary"), interpret)
-    kwargs = {"compiler_params": params} if params is not None else {}
     seq_spec = pl.BlockSpec((1, Q, D), lambda bh, c: (bh, c, 0))
+    state_spec = pl.BlockSpec((1, D, D), lambda bh, c: (bh, 0, 0))
     y, s_fin = pl.pallas_call(
-        body,
-        grid=grid,
+        functools.partial(_body, Q=Q),
+        grid=(B * H, L // Q),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, D), lambda bh, c: (bh % H, 0)),
-                  pl.BlockSpec((1, D, D), lambda bh, c: (bh, 0, 0))],
-        out_specs=[seq_spec,
-                   pl.BlockSpec((1, D, D), lambda bh, c: (bh, 0, 0))],
+                  pl.BlockSpec((1, D, 1), lambda bh, c: (bh % H, 0, 0)),
+                  state_spec],
+        out_specs=[seq_spec, state_spec],
         out_shape=[jax.ShapeDtypeStruct((B * H, L, D), r.dtype),
                    jax.ShapeDtypeStruct((B * H, D, D), jnp.float32)],
         scratch_shapes=[vmem_scratch((D, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
-    )(rf, kf, vf, wf, u, s0f)
+        compiler_params=compiler_params(("parallel", "arbitrary")),
+    )(rf, kf, vf, wf, u.reshape(H, D, 1), s0f)
     y = jnp.moveaxis(y.reshape(B, H, L, D), 1, 2)
     return y, s_fin.reshape(B, H, D, D)

@@ -49,6 +49,7 @@ from ..configs import CNN_REGISTRY, get_config
 from ..models import get_model, init_params
 from ..obs import Observability
 from ..serving import Request, ServingEngine
+from .compile_cache import use_compile_cache
 
 
 def _build_obs(args) -> Observability:
@@ -191,6 +192,7 @@ def main(argv=None) -> None:
                     help="print a one-line console dashboard every N "
                          "engine ticks; 0 = off")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.paged and not args.program:
         print("error: --paged requires --program (the paged plan only "
               "exists on the stateful Program path)", file=sys.stderr)

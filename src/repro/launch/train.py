@@ -12,7 +12,6 @@ straggler log) comes from runtime/Trainer.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import logging
 
 import jax
@@ -24,9 +23,18 @@ from ..models import get_model, init_params
 from ..optim import AdamW, cosine_schedule
 from ..parallel.rules import make_plan
 from ..runtime import Trainer, TrainerConfig
+from .compile_cache import use_compile_cache
 from .steps import build_step
 from ..core.hw import MeshDescriptor
 from .mesh import make_mesh_from_descriptor
+
+
+def train_mesh(n_dev: int) -> MeshDescriptor:
+    """The trainer's (data, model) mesh over the first ``n_dev``
+    devices: a model axis of 2 from four devices up, else data only."""
+    if n_dev >= 4:
+        return MeshDescriptor((n_dev // 2, 2), ("data", "model"))
+    return MeshDescriptor((n_dev, 1), ("data", "model"))
 
 
 def main(argv=None):
@@ -45,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--data", default="synthetic",
                     help="'synthetic' or a packed-token file path")
     args = ap.parse_args(argv)
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
 
@@ -53,11 +62,7 @@ def main(argv=None):
         cfg = cfg.smoke()
     shape = ShapeSpec("cli_train", args.seq, args.batch, "train")
 
-    n_dev = len(jax.devices())
-    if n_dev >= 4:
-        desc = MeshDescriptor((n_dev // 2, 2), ("data", "model"))
-    else:
-        desc = MeshDescriptor((n_dev, 1), ("data", "model"))
+    desc = train_mesh(len(jax.devices()))
     mesh = make_mesh_from_descriptor(desc)
     plan = make_plan(cfg, shape, desc, args.strategy)
     optimizer = AdamW(lr=cosine_schedule(args.lr, warmup=20,

@@ -16,6 +16,7 @@ keeps the 512-device dry-run compile tractable.
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -80,9 +81,13 @@ def tree_paths(defs: dict, prefix: str = "") -> list[str]:
 
 def init_params(defs: dict, rng: jax.Array) -> dict:
     """Initialize a ParamDef tree to concrete arrays (deterministic per
-    path, so restores and re-inits agree regardless of traversal order)."""
+    path, so restores and re-inits agree regardless of traversal order).
+    The path is folded in by CRC-32, not ``hash()``: str hashes are
+    salted per process, so the same seed would give other weights in
+    every run."""
     paths = tree_paths(defs)
-    keys = {p: jax.random.fold_in(rng, hash(p) % (2 ** 31)) for p in paths}
+    keys = {p: jax.random.fold_in(rng, zlib.crc32(p.encode()) % (2 ** 31))
+            for p in paths}
 
     def go(sub: dict, prefix: str) -> dict:
         out = {}

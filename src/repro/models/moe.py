@@ -31,7 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.balance import moe_capacity
 from ..parallel.act_sharding import _CTX, shard_act
-from ..parallel.compat import shard_map
 from ..kernels.common import apply_activation
 
 __all__ = ["moe_mlp"]
@@ -175,7 +174,7 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     body = functools.partial(fn, axes=dp, model_axis=mdl)
     f_spec = P(None, None, mdl)        # w_gate / w_up: F-sharded
     d_spec = P(None, mdl, None)        # w_down: F-sharded on dim 1
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None), P(None, None), f_spec, f_spec, d_spec),
         out_specs=(P(dp, None),

@@ -19,7 +19,6 @@ executes the instruction stream through runtime/executor.py.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any
 
 import jax
@@ -41,8 +40,8 @@ from ..core.schedule import compile_model
 from ..kernels.decode_attention import (decode_attention, ring_kv_len,
                                         ring_positions)
 from ..kernels.flash_attention import flash_attention
-from ..kernels.common import apply_activation
-from ..parallel.act_sharding import shard_act
+from ..kernels.common import apply_activation, resolve_impl
+from ..parallel.act_sharding import per_shard, shard_act
 from .common import (ParamDef, Rotary, apply_rope, layer_norm, rms_norm)
 from .moe import moe_mlp
 
@@ -184,7 +183,12 @@ def _attention(h, p, cfg, cos, sin, *, impl, causal=True, window=None,
     # of a full-score all-reduce per kv chunk (§Perf H2 iter 2).
     k = shard_act(k, "attn_kv")
     v = shard_act(v, "attn_kv")
-    out = flash_attention(q, k, v, causal=causal, window=window, impl=impl)
+    attend = functools.partial(flash_attention, causal=causal, window=window,
+                               impl=impl)
+    if resolve_impl(impl) == "reference":
+        out = attend(q, k, v)
+    else:   # a Pallas kernel on a mesh runs per shard
+        out = per_shard(attend, (q, "attn_q"), (k, "attn_kv"), (v, "attn_kv"))
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     out = out @ p["wo"]
     if return_kv:

@@ -14,7 +14,7 @@ import contextvars
 import jax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["shard_act", "activation_rules", "ActivationRules"]
+__all__ = ["shard_act", "activation_rules", "ActivationRules", "per_shard"]
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar("act_rules",
                                                       default=None)
@@ -27,15 +27,13 @@ class ActivationRules:
         self.specs = specs
         self.mesh = mesh
 
-    def constrain(self, x: jax.Array, name: str) -> jax.Array:
-        spec = self.specs.get(name)
-        if spec is None:
-            return x
-        # Trim the spec to the array rank (specs are written for the
-        # canonical rank; reduced ranks drop trailing axes) and drop
-        # entries whose dimension the mesh axis does not divide.
+    def spec(self, x: jax.Array, name: str) -> P:
+        """The rule for ``name`` fitted to ``x``: trimmed to its rank
+        (specs are written for the canonical rank; reduced ranks drop
+        trailing axes), with entries whose dimension the mesh axis does
+        not divide dropped.  Unknown names are unsharded."""
         sizes = dict(self.mesh.shape) if self.mesh is not None else {}
-        entries = list(spec)[:x.ndim]
+        entries = list(self.specs.get(name, ()))[:x.ndim]
         while len(entries) < x.ndim:
             entries.append(None)
         fixed = []
@@ -45,7 +43,12 @@ class ActivationRules:
             for n in names:
                 total *= sizes.get(n, 1)
             fixed.append(e if (total and dim % total == 0) else None)
-        return jax.lax.with_sharding_constraint(x, P(*fixed))
+        return P(*fixed)
+
+    def constrain(self, x: jax.Array, name: str) -> jax.Array:
+        if name not in self.specs:
+            return x
+        return jax.lax.with_sharding_constraint(x, self.spec(x, name))
 
 
 @contextlib.contextmanager
@@ -62,6 +65,29 @@ def shard_act(x: jax.Array, name: str) -> jax.Array:
     if rules is None:
         return x
     return rules.constrain(x, name)
+
+
+def per_shard(fn, *named):
+    """``fn(*xs)`` for named activations ``(x, name)``, run on each
+    device's shard when a mesh context is active: the compiler cannot
+    partition a Pallas kernel, so a kernel on a mesh is called per shard.
+
+    For (batch, heads, ...) operands such as attention's q/k/v: dims 0
+    and 1 keep the rules' sharding of the first operand — heads only
+    where every operand shards them alike, so a GQA group never
+    straddles devices — and every later dim is whole on each device.
+    The output is sharded like the first operand.  Outside a mesh
+    context this is a plain call."""
+    xs = [x for x, _ in named]
+    rules = _CTX.get()
+    if rules is None or rules.mesh is None:
+        return fn(*xs)
+    specs = [rules.spec(x, n) for x, n in named]
+    batch = specs[0][0]
+    heads = specs[0][1] if len({s[1] for s in specs}) == 1 else None
+    in_specs = tuple(P(batch, heads, *([None] * (x.ndim - 2))) for x in xs)
+    return jax.shard_map(fn, mesh=rules.mesh, in_specs=in_specs,
+                         out_specs=in_specs[0], check_vma=False)(*xs)
 
 
 def data_shards() -> int:

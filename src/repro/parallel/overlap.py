@@ -16,13 +16,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .compat import axis_size
 
 __all__ = ["all_gather_matmul", "matmul_reduce_scatter"]
 
 
 def _ring(axis_name):
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     return [(i, (i + 1) % g) for i in range(g)]
 
 
@@ -34,7 +33,7 @@ def all_gather_matmul(x: jax.Array, w_shard: jax.Array,
     around the ring so every transfer overlaps a partial matmul — the
     weight-gathered (ICI-Kloop) execution with T4 chunking applied.
     """
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = x.shape[0]
     Nl = w_shard.shape[1]
@@ -59,7 +58,7 @@ def matmul_reduce_scatter(x: jax.Array, w_shard: jax.Array,
     products while they travel — each hop's transfer overlaps the next
     partial matmul (the activation-gathered / ICI-Mloop direction).
     """
-    g = axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     N = w_shard.shape[1]
     assert N % g == 0

@@ -5,6 +5,9 @@ and runs one forward + one train step on CPU, asserting output shapes
 and no NaNs — the deliverable-(f) smoke contract.
 """
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +146,27 @@ def test_resnet18_graph_residual_count():
     g = cnn.to_graph(CNN_REGISTRY["resnet18"], batch=1)
     sinks = [n for n in g if n.bypass_of]
     assert len(sinks) == 8          # 2 blocks x 4 stages
+
+
+def test_init_params_same_across_processes():
+    """A seed fixes the weights in every process: str hashes are salted
+    per process, so the per-path key must not come from ``hash()``."""
+    script = ("import jax\n"
+              "from repro.configs import get_config\n"
+              "from repro.models import get_model, init_params\n"
+              "cfg = get_config('smollm-360m').smoke()\n"
+              "p = init_params(get_model(cfg).param_defs(cfg),"
+              " jax.random.PRNGKey(0))\n"
+              "print(repr(float(sum(abs(l).sum()"
+              " for l in jax.tree.leaves(p)))))\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    sums = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
+                   PYTHONHASHSEED=salt)
+        proc = subprocess.run([sys.executable, "-c", script],
+                              capture_output=True, text=True, env=env,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        sums.append(proc.stdout.strip().splitlines()[-1])
+    assert sums[0] == sums[1], sums

@@ -13,7 +13,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.overlap import all_gather_matmul, matmul_reduce_scatter
 
 mesh = jax.sharding.Mesh(np.asarray(jax.devices()).reshape(8), ("model",))
@@ -24,7 +23,7 @@ w = jax.random.normal(ks[1], (K, N), jnp.float32) * 0.1
 ref = x @ w
 
 # weight-gathered (ICI-Kloop) with overlap
-agm = jax.jit(shard_map(
+agm = jax.jit(jax.shard_map(
     lambda x, w: all_gather_matmul(x, w, "model"), mesh=mesh,
     in_specs=(P(None, None), P(None, "model")),
     out_specs=P(None, None), axis_names={"model"}, check_vma=False))
@@ -32,7 +31,7 @@ out = agm(x, w)
 err1 = float(jnp.abs(out - ref).max())
 
 # activation-contracted reduce-scatter (ICI-Mloop) with overlap
-mrs = jax.jit(shard_map(
+mrs = jax.jit(jax.shard_map(
     lambda x, w: matmul_reduce_scatter(x, w, "model"), mesh=mesh,
     in_specs=(P(None, "model"), P("model", None)),
     out_specs=P(None, "model"), axis_names={"model"}, check_vma=False))
