@@ -53,8 +53,7 @@ from .compile_cache import use_compile_cache
 
 
 def _build_obs(args) -> Observability:
-    return Observability(flight_path=args.flight_out,
-                         sample_ops_every=args.sample_ops)
+    return Observability(flight_path=args.flight_out)
 
 
 def _write_artifacts(args, obs: Observability) -> None:
@@ -183,11 +182,6 @@ def main(argv=None) -> None:
                          "per-request lifecycle events + per-tick "
                          "snapshots) to PATH; replay offline with "
                          "repro.obs.replay_summary")
-    ap.add_argument("--sample-ops", type=int, default=0, metavar="N",
-                    help="time one decode tick per N through the "
-                         "Stage-7 trace recorder (op_time_us{kind} "
-                         "histograms + op_sample flight events); "
-                         "0 = off")
     ap.add_argument("--dash-every", type=int, default=0, metavar="N",
                     help="print a one-line console dashboard every N "
                          "engine ticks; 0 = off")

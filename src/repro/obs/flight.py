@@ -49,7 +49,6 @@ EVENT_FIELDS: dict[str, tuple] = {
     "tick":          ("tick", "dt_ms", "live", "queue_depth",
                       "free_pages", "starved"),
     "fallback":      ("reason",),
-    "op_sample":     ("kind", "name", "measured_time_s"),
 }
 
 

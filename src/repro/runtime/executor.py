@@ -66,7 +66,7 @@ __all__ = ["run", "jitted_runner", "ProgramState", "init_program_state",
            "jitted_prefill_runner", "jitted_chunk_runner",
            "jitted_decode_runner", "PagePool", "paged_pool_regions",
            "sync_page_table", "apply_page_copies", "TraceRecord",
-           "ExecutorTrace", "trace_program", "OpTimingSampler"]
+           "ExecutorTrace", "trace_program", "op_scope"]
 
 
 def _param(params, key: str | None):
@@ -311,6 +311,15 @@ def _run_op(op: ProgramOp, src: jax.Array, regions: dict, params, *,
     raise NotImplementedError(f"unknown program kernel {op.kernel}")
 
 
+def op_scope(op: ProgramOp) -> str:
+    """The ``jax.named_scope`` an op's dispatch runs under,
+    ``<kernel>.<name>`` (``decode_attention.l3.attn``): everything the
+    op emits, its cache writes and layout copies included, carries it
+    in the HLO ``op_name`` metadata, kernel first so that a trace
+    reader matches an op kind by prefix."""
+    return f"{op.kernel}.{op.name}"
+
+
 def run(program: Program, params, x: jax.Array, *, impl: str = "auto",
         interpret: bool | None = None) -> jax.Array:
     """Execute ``program`` against ``params`` on input ``x``.
@@ -324,18 +333,19 @@ def run(program: Program, params, x: jax.Array, *, impl: str = "auto",
     """
     regions: dict[int, jax.Array] = {program.input_region: x}
     for op in program.ops:
-        if op.kernel == "decode_attention":
-            raise ValueError(
-                f"op {op.name} needs a ProgramState (persistent KV "
-                f"regions); use run_decode for decode Programs")
-        if op.kernel in _FAMILY_KERNELS:
-            regions[op.out_region] = _run_family_op(
-                op, regions[op.in_region], regions, params, None,
-                impl=impl, interpret=interpret)
-            continue
-        regions[op.out_region] = _run_op(op, regions[op.in_region], regions,
-                                         params, impl=impl,
-                                         interpret=interpret)
+        with jax.named_scope(op_scope(op)):
+            if op.kernel == "decode_attention":
+                raise ValueError(
+                    f"op {op.name} needs a ProgramState (persistent KV "
+                    f"regions); use run_decode for decode Programs")
+            if op.kernel in _FAMILY_KERNELS:
+                regions[op.out_region] = _run_family_op(
+                    op, regions[op.in_region], regions, params, None,
+                    impl=impl, interpret=interpret)
+                continue
+            regions[op.out_region] = _run_op(
+                op, regions[op.in_region], regions, params, impl=impl,
+                interpret=interpret)
     return regions[program.output_region]
 
 
@@ -470,24 +480,27 @@ def run_prefill(program: Program, params, tokens: jax.Array,
     regions: dict[int, jax.Array] = {program.input_region: tokens}
     caches = dict(state.caches)
     for op in program.ops:
-        src = regions[op.in_region]
-        if op.kernel == "flash_attention" and op.k_cache_region is not None:
-            out, k, v = _run_attention(op, regions, impl=impl,
-                                       interpret=interpret, return_kv=True)
-            if op.page_table_region is not None:
-                _write_prefill_cache_paged(caches, op, k, v, slot, length,
-                                           write_from)
-            else:
-                _write_prefill_cache(caches, op, k, v, slot, length)
-            regions[op.out_region] = out
-            continue
-        if op.kernel in _FAMILY_KERNELS:
-            regions[op.out_region] = _run_family_op(
-                op, src, regions, params, caches, slot=slot,
-                length=length, impl=impl, interpret=interpret)
-            continue
-        regions[op.out_region] = _run_op(op, src, regions, params,
-                                         impl=impl, interpret=interpret)
+        with jax.named_scope(op_scope(op)):
+            src = regions[op.in_region]
+            if (op.kernel == "flash_attention"
+                    and op.k_cache_region is not None):
+                out, k, v = _run_attention(op, regions, impl=impl,
+                                           interpret=interpret,
+                                           return_kv=True)
+                if op.page_table_region is not None:
+                    _write_prefill_cache_paged(caches, op, k, v, slot,
+                                               length, write_from)
+                else:
+                    _write_prefill_cache(caches, op, k, v, slot, length)
+                regions[op.out_region] = out
+                continue
+            if op.kernel in _FAMILY_KERNELS:
+                regions[op.out_region] = _run_family_op(
+                    op, src, regions, params, caches, slot=slot,
+                    length=length, impl=impl, interpret=interpret)
+                continue
+            regions[op.out_region] = _run_op(op, src, regions, params,
+                                             impl=impl, interpret=interpret)
     lengths = state.lengths.at[slot].set(jnp.asarray(length, jnp.int32))
     return regions[program.output_region], ProgramState(caches, lengths)
 
@@ -655,21 +668,23 @@ def run_prefill_chunk(program: Program, params, tokens: jax.Array,
     write_from = (jnp.zeros_like(start) if write_from is None
                   else jnp.asarray(write_from, jnp.int32))
     for op in program.ops:
-        src = regions[op.in_region]
-        if op.kernel == "flash_attention" and op.k_cache_region is not None:
-            out, k, v = _run_attention_chunk(op, regions, caches, slot,
-                                             start, impl=impl,
-                                             interpret=interpret)
-            if op.page_table_region is not None:
-                _write_chunk_cache_paged(caches, op, k, v, slot, start,
-                                         stop, length, write_from)
-            else:
-                _write_chunk_cache(caches, op, k, v, slot, start, stop,
-                                   length)
-            regions[op.out_region] = out
-            continue
-        regions[op.out_region] = _run_op(op, src, regions, params,
-                                         impl=impl, interpret=interpret)
+        with jax.named_scope(op_scope(op)):
+            src = regions[op.in_region]
+            if (op.kernel == "flash_attention"
+                    and op.k_cache_region is not None):
+                out, k, v = _run_attention_chunk(op, regions, caches, slot,
+                                                 start, impl=impl,
+                                                 interpret=interpret)
+                if op.page_table_region is not None:
+                    _write_chunk_cache_paged(caches, op, k, v, slot, start,
+                                             stop, length, write_from)
+                else:
+                    _write_chunk_cache(caches, op, k, v, slot, start, stop,
+                                       length)
+                regions[op.out_region] = out
+                continue
+            regions[op.out_region] = _run_op(op, src, regions, params,
+                                             impl=impl, interpret=interpret)
     lengths = state.lengths.at[slot].set(stop)
     return regions[program.output_region], ProgramState(caches, lengths)
 
@@ -681,13 +696,13 @@ def jitted_chunk_runner(program: Program, impl: str = "auto",
     executable per in-flight batch width B (XLA re-specializes on the
     leading shape; the engine's chunk batches are small and repeat)."""
     def make():
-        def _run(params, tokens, state, slot, start, stop, length,
-                 write_from, _program=program):
+        def program_chunk(params, tokens, state, slot, start, stop,
+                          length, write_from, _program=program):
             return run_prefill_chunk(_program, params, tokens, state,
                                      slot, start, stop, length,
                                      write_from, impl=impl,
                                      interpret=interpret)
-        return jax.jit(_run, donate_argnums=(2,))
+        return jax.jit(program_chunk, donate_argnums=(2,))
     return _cached_runner((id(program), impl, interpret, "chunk"), make)
 
 
@@ -837,37 +852,38 @@ def run_decode(program: Program, params, tokens: jax.Array,
     live = (jnp.ones(pos.shape, bool) if mask is None
             else jnp.asarray(mask, bool))
     for op in program.ops:
-        src = regions[op.in_region]
-        if op.kernel == "decode_attention":
-            if op.page_table_region is not None:
-                quant = op.k_scale_region is not None
-                out, ck, cv, ks, vs = _run_decode_attention_paged(
-                    op, src, regions[op.k_region], regions[op.v_region],
-                    caches[op.k_cache_region], caches[op.v_cache_region],
-                    caches[op.k_scale_region] if quant else None,
-                    caches[op.v_scale_region] if quant else None,
-                    caches[op.page_table_region], pos, live,
+        with jax.named_scope(op_scope(op)):
+            src = regions[op.in_region]
+            if op.kernel == "decode_attention":
+                if op.page_table_region is not None:
+                    quant = op.k_scale_region is not None
+                    out, ck, cv, ks, vs = _run_decode_attention_paged(
+                        op, src, regions[op.k_region], regions[op.v_region],
+                        caches[op.k_cache_region], caches[op.v_cache_region],
+                        caches[op.k_scale_region] if quant else None,
+                        caches[op.v_scale_region] if quant else None,
+                        caches[op.page_table_region], pos, live,
+                        impl=impl, interpret=interpret)
+                    if quant:
+                        caches[op.k_scale_region] = ks
+                        caches[op.v_scale_region] = vs
+                else:
+                    out, ck, cv = _run_decode_attention(
+                        op, src, regions[op.k_region], regions[op.v_region],
+                        caches[op.k_cache_region], caches[op.v_cache_region],
+                        pos, live, impl=impl, interpret=interpret)
+                caches[op.k_cache_region] = ck
+                caches[op.v_cache_region] = cv
+                regions[op.out_region] = out
+                continue
+            if op.kernel in _FAMILY_KERNELS:
+                regions[op.out_region] = _run_family_op(
+                    op, src, regions, params, caches, live=live,
                     impl=impl, interpret=interpret)
-                if quant:
-                    caches[op.k_scale_region] = ks
-                    caches[op.v_scale_region] = vs
-            else:
-                out, ck, cv = _run_decode_attention(
-                    op, src, regions[op.k_region], regions[op.v_region],
-                    caches[op.k_cache_region], caches[op.v_cache_region],
-                    pos, live, impl=impl, interpret=interpret)
-            caches[op.k_cache_region] = ck
-            caches[op.v_cache_region] = cv
-            regions[op.out_region] = out
-            continue
-        if op.kernel in _FAMILY_KERNELS:
-            regions[op.out_region] = _run_family_op(
-                op, src, regions, params, caches, live=live,
-                impl=impl, interpret=interpret)
-            continue
-        regions[op.out_region] = _run_op(op, src, regions, params,
-                                         impl=impl, interpret=interpret,
-                                         pos=pos)
+                continue
+            regions[op.out_region] = _run_op(op, src, regions, params,
+                                             impl=impl, interpret=interpret,
+                                             pos=pos)
     return (regions[program.output_region],
             ProgramState(caches, jnp.where(live, pos + 1, pos)))
 
@@ -887,9 +903,9 @@ def jitted_runner(program: Program, impl: str = "auto",
     variants cannot pin programs + compiled executables forever.
     """
     def make():
-        def _run(params, x, _program=program):
+        def program_run(params, x, _program=program):
             return run(_program, params, x, impl=impl, interpret=interpret)
-        return jax.jit(_run)
+        return jax.jit(program_run)
     return _cached_runner((id(program), impl, interpret, "run"), make)
 
 
@@ -911,12 +927,12 @@ def jitted_prefill_runner(program: Program, impl: str = "auto",
     the cache buffers update in place; ``write_from`` (paged plans
     only) is the shared-prefix row the cache writes start at."""
     def make():
-        def _run(params, tokens, state, slot, length, write_from=0,
-                 _program=program):
+        def program_prefill(params, tokens, state, slot, length,
+                            write_from=0, _program=program):
             return run_prefill(_program, params, tokens, state, slot,
                                length, write_from, impl=impl,
                                interpret=interpret)
-        return jax.jit(_run, donate_argnums=(2,))
+        return jax.jit(program_prefill, donate_argnums=(2,))
     return _cached_runner((id(program), impl, interpret, "prefill"), make)
 
 
@@ -927,10 +943,11 @@ def jitted_decode_runner(program: Program, impl: str = "auto",
     serving hot loop.  ``mask`` is the (slots,) bool occupancy; omitted
     means every slot is live."""
     def make():
-        def _run(params, tokens, state, mask=None, _program=program):
+        def program_decode(params, tokens, state, mask=None,
+                           _program=program):
             return run_decode(_program, params, tokens, state, mask,
                               impl=impl, interpret=interpret)
-        return jax.jit(_run, donate_argnums=(2,))
+        return jax.jit(program_decode, donate_argnums=(2,))
     return _cached_runner((id(program), impl, interpret, "decode"), make)
 
 
@@ -1366,70 +1383,3 @@ def trace_program(program: Program, params, x: jax.Array, *,
             measured_time_s=_time_thunk(thunk, repeats) if measure else None,
             repeats=repeats if measure else 0, extras=extras))
     return trace
-
-
-class OpTimingSampler:
-    """Cheap sampled op-timing for serving ticks (Stage 8).
-
-    Full trace mode (``trace_program`` with repeats) is far too heavy
-    for a serving loop, but *sampling* it is not: every ``every``-th
-    ``tick()`` call runs one eager traced execution of the decode
-    Program against the live ``ProgramState`` — same ``TraceRecord``
-    schema as Stage 7, single repeat — and attributes the measured
-    wallclock to op kinds on the metrics plane
-    (``op_time_us{kind=...}`` histograms) plus one ``op_sample``
-    flight event per op.  The other ``every - 1`` ticks cost exactly
-    one integer increment.
-
-    The eager walk is *read-only* with respect to the engine:
-    ``trace_program`` copies the cache dict and produces new arrays,
-    so the donated state buffers the jitted tick consumes afterwards
-    are untouched — which is also why the engine samples *before* its
-    jitted decode call (after it, donation may have invalidated the
-    buffers the tracer would read).
-    """
-
-    def __init__(self, every: int, registry=None, flight=None, *,
-                 impl: str = "auto", interpret: bool | None = None,
-                 repeats: int = 1):
-        if every < 0:
-            raise ValueError(f"sample cadence must be >= 0, got {every}")
-        self.every = every
-        self.registry = registry
-        self.flight = flight
-        self.impl = impl
-        self.interpret = interpret
-        self.repeats = repeats
-        self.n_calls = 0
-        self.n_samples = 0
-
-    def tick(self, program: Program, params, tokens, *,
-             state: ProgramState | None = None,
-             mask=None) -> ExecutorTrace | None:
-        """Count one tick; on the sampled ones, trace-and-time the
-        Program and feed the records to the metrics/flight planes.
-        Returns the trace on sampled ticks, None otherwise."""
-        if not self.every:
-            return None
-        self.n_calls += 1
-        if self.n_calls % self.every:
-            return None
-        trace = trace_program(program, params, tokens, impl=self.impl,
-                              interpret=self.interpret,
-                              repeats=self.repeats, measure=True,
-                              state=state, mask=mask)
-        self.n_samples += 1
-        for rec in trace.records:
-            if self.registry is not None:
-                self.registry.histogram(
-                    "op_time_us",
-                    help="sampled per-op executor wallclock",
-                    kind=rec.kind).observe(rec.measured_time_s * 1e6)
-            if self.flight is not None:
-                self.flight.event(
-                    "op_sample", kind=rec.kind, name=rec.name,
-                    index=rec.index, flops=rec.flops,
-                    traffic_bytes=rec.traffic_bytes,
-                    modeled_time_s=rec.modeled_time_s,
-                    measured_time_s=rec.measured_time_s)
-        return trace

@@ -85,8 +85,23 @@ when a flight recorder is attached every request's lifecycle — enqueue
 accept/rollback → COW fork → release — plus a per-tick engine snapshot
 streams to JSONL (``obs.flight.replay_summary`` reconstructs the token
 streams exactly).  The default bundle is counters-only: no recorder,
-no op sampling, no extra device syncs — a bare engine pays a few float
-adds per tick for its metrics plane.
+no extra device syncs — a bare engine pays a few float adds per tick
+for its metrics plane.
+
+**Phases** (``obs.span``): ``serving.step`` covers a tick and its
+children tile it — on the CNN path ``serving.stage`` (batch, stack,
+pad, transfer), ``serving.dispatch``, ``serving.wait`` (the logits'
+``block_until_ready``), ``serving.fetch`` (to numpy) and
+``serving.sample`` (argmax, retire); on the LM Program path
+``serving.admit`` (one ``serving.prefill`` per admitted request inside
+it), ``serving.chunk`` (chunked prefill), ``serving.pages`` (paged
+plans), then ``serving.dispatch`` / ``serving.wait`` /
+``serving.fetch`` / ``serving.sample`` for the decode tick, or
+``serving.spec`` for a speculative one.  ``serving.submit`` covers
+``submit``.  Each phase's seconds and count are counters on the
+registry (``serving_phase_seconds_total{phase=...}``), and while a
+profiler runs each span is a host annotation on the device trace's
+clock.
 """
 from __future__ import annotations
 
@@ -154,9 +169,9 @@ class ServingEngine:
         self.live: dict[int, Request] = {}       # slot -> request
         self.queue: list[Request] = []           # legacy/CNN paths only
         # One metrics plane + flight recorder per engine (Stage 8).
-        # The default bundle is counters-only (no recorder, no op
-        # sampling); callers that want the flight record / Prometheus
-        # snapshot / sampled op timings pass their own bundle.
+        # The default bundle is counters-only (no recorder); callers
+        # that want the flight record / Prometheus snapshot pass their
+        # own bundle.
         self.obs = obs if obs is not None else Observability()
         self._init_metrics()
         # LM-program requests enter through the bounded admission queue
@@ -171,7 +186,6 @@ class ServingEngine:
         self._prefilling: dict[int, _InFlightPrefill] = {}
         self._lm_program = False
         self._tick_no = 0
-        self._op_sampler = None
         # Why an LM config requested on the program path fell back to
         # the legacy decode loop (None = no fallback happened); callers
         # that *require* the program path (launch/serve.py --program)
@@ -305,16 +319,6 @@ class ServingEngine:
                                if (chunk_size is not None or spec_k)
                                else None)
                 self._init_spec(pair, draft_cfg, draft_params)
-                if self.obs.sample_ops_every:
-                    # Stage-8 sampled op timing: every N-th decode tick
-                    # is additionally walked eagerly through the
-                    # Stage-7 trace recorder (TraceRecord schema) so
-                    # tick wallclock attributes to op kinds without
-                    # full trace mode.
-                    self._op_sampler = executor.OpTimingSampler(
-                        self.obs.sample_ops_every,
-                        registry=self.obs.registry,
-                        flight=self.obs.flight, impl=impl)
                 self._lm_program = True
                 return
         if chunk_size is not None or spec_k:
@@ -486,6 +490,10 @@ class ServingEngine:
         with reason ``queue_full`` when the bounded queue is at
         capacity — the request is *not* held).  Stamps the enqueue
         time (TTFT starts here) and records the lifecycle events."""
+        with self.obs.span("serving.submit"):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> AdmissionTicket:
         req._enqueue_t = self.obs.clock()
         self._c_requests.inc()
         prompt_len = (len(req.prompt)
@@ -582,17 +590,26 @@ class ServingEngine:
         ``out_tokens`` carries the argmax class id."""
         if not self.queue:
             return []
-        batch, self.queue = self.queue[:self.slots], self.queue[self.slots:]
-        images = np.stack([np.asarray(r.prompt) for r in batch])
-        if len(batch) < self.slots:        # pad to the compiled batch
-            pad = np.zeros((self.slots - len(batch),) + images.shape[1:],
-                           images.dtype)
-            images = np.concatenate([images, pad])
-        logits = np.asarray(self._infer(
-            self.params, jnp.asarray(images, self.cfg.jdtype)))
-        for r, lg in zip(batch, logits):
-            r.out_tokens.append(int(np.argmax(lg)))
-            r.done = True
+        span = self.obs.span
+        with span("serving.stage"):
+            batch = self.queue[:self.slots]
+            self.queue = self.queue[self.slots:]
+            images = np.stack([np.asarray(r.prompt) for r in batch])
+            if len(batch) < self.slots:        # pad to the compiled batch
+                pad = np.zeros((self.slots - len(batch),) + images.shape[1:],
+                               images.dtype)
+                images = np.concatenate([images, pad])
+            images = jnp.asarray(images, self.cfg.jdtype)
+        with span("serving.dispatch"):
+            logits = self._infer(self.params, images)
+        with span("serving.wait"):
+            jax.block_until_ready(logits)
+        with span("serving.fetch"):
+            logits = np.asarray(logits)
+        with span("serving.sample"):
+            for r, lg in zip(batch, logits):
+                r.out_tokens.append(int(np.argmax(lg)))
+                r.done = True
         return batch
 
     # -- LM program fast path ----------------------------------------------------
@@ -720,14 +737,15 @@ class ServingEngine:
                     write_from=write_from,
                     admitted_tick=self.n_decode_ticks)
                 continue
-            padded = np.zeros((1, self.max_len), np.int32)
-            padded[0, :len(win)] = win
-            logits, self.state = self._prefill(
-                self.params, jnp.asarray(padded), self.state, slot,
-                len(win), write_from)
-            self._finish_prefill(slot, req, padded,
-                                 np.asarray(logits[0, len(win) - 1]),
-                                 len(win), finished)
+            with self.obs.span("serving.prefill", uid=req.uid):
+                padded = np.zeros((1, self.max_len), np.int32)
+                padded[0, :len(win)] = win
+                logits, self.state = self._prefill(
+                    self.params, jnp.asarray(padded), self.state, slot,
+                    len(win), write_from)
+                self._finish_prefill(slot, req, padded,
+                                     np.asarray(logits[0, len(win) - 1]),
+                                     len(win), finished)
 
     def _write_encoder_memory(self, slot: int, req: Request) -> None:
         """Run the family's admission-time memory writer (the whisper
@@ -865,66 +883,77 @@ class ServingEngine:
         can never stall the in-flight streams (``n_starved_ticks``
         counts violations; it stays 0 by construction)."""
         finished: list[Request] = []
-        self._lm_admit(finished)
-        self._advance_prefills(finished)
+        span = self.obs.span
+        with span("serving.admit"):
+            self._lm_admit(finished)
+        if self._prefilling:
+            with span("serving.chunk"):
+                self._advance_prefills(finished)
         if not self.live:
             return finished
         starved = set(self.live)
+        if self._pool is not None:
+            with span("serving.pages"):
+                self._prepare_pages()
+        if self._spec:
+            with span("serving.spec"):
+                advanced = self._spec_tick(*self._decode_inputs(),
+                                           finished)
+        else:
+            with span("serving.dispatch"):
+                toks, occupied = self._decode_inputs()
+                logits, self.state = self._decode(self.params,
+                                                  jnp.asarray(toks),
+                                                  self.state,
+                                                  jnp.asarray(occupied))
+                if self._pool is not None:
+                    for slot in self.live:
+                        self._slot_len[slot] += 1
+            with span("serving.wait"):
+                jax.block_until_ready(logits)
+            with span("serving.fetch"):
+                logits = np.asarray(logits)
+            with span("serving.sample"):
+                advanced = set()
+                for slot, req in list(self.live.items()):
+                    nxt = self._next_token(req, logits[slot])
+                    self._retire_if_done(slot, req, nxt, finished)
+                    advanced.add(slot)
+        self._c_decode_ticks.inc()
+        if starved - advanced:
+            self._c_starved.inc(len(starved - advanced))
+        return finished
+
+    def _decode_inputs(self) -> tuple[np.ndarray, np.ndarray]:
+        """Each slot's last token and the occupancy mask.  The mask
+        keeps dead slots inert inside run_decode: no length advance, no
+        cache-row write (slot-cache hygiene for the rolling-window
+        plans, whose prefill does not rewrite the whole row region on
+        re-admission)."""
         toks = np.zeros((self.slots,), np.int32)
         occupied = np.zeros((self.slots,), bool)
         for slot, req in self.live.items():
             toks[slot] = req._last_token
             occupied[slot] = True
-        # The occupancy mask keeps dead slots inert inside run_decode:
-        # no length advance, no cache-row write (slot-cache hygiene for
-        # the rolling-window plans, whose prefill does not rewrite the
-        # whole row region on re-admission).
-        if self._pool is not None:
-            # Make each live slot's write page real and private before
-            # the jitted tick: allocate on-demand past the prompt,
-            # COW-fork shared pages (device page copy), then push the
-            # decided table.
-            from ..runtime import executor
-            copies = []
-            for slot in self.live:
-                c = self._pool.prepare_decode(slot, self._slot_len[slot])
-                if c is not None:
-                    copies.append(c)
-                    self.obs.flight.event("cow_fork", slot=slot,
-                                          src_page=int(c[0]),
-                                          dst_page=int(c[1]))
-            executor.sync_page_table(self.state, self.program, self._pool)
-            if copies:
-                executor.apply_page_copies(self.state, self.program,
-                                           copies)
-                self._c_cow_forks.inc(len(copies))
-        if self._spec:
-            advanced = self._spec_tick(toks, occupied, finished)
-        else:
-            if self._op_sampler is not None:
-                # Sample *before* the jitted decode: the runner donates
-                # the state buffers, so an eager trace afterwards would
-                # walk invalidated caches.
-                self._op_sampler.tick(self.program.decode, self.params,
-                                      jnp.asarray(toks), state=self.state,
-                                      mask=jnp.asarray(occupied))
-            logits, self.state = self._decode(self.params,
-                                              jnp.asarray(toks),
-                                              self.state,
-                                              jnp.asarray(occupied))
-            if self._pool is not None:
-                for slot in self.live:
-                    self._slot_len[slot] += 1
-            logits = np.asarray(logits)
-            advanced = set()
-            for slot, req in list(self.live.items()):
-                nxt = self._next_token(req, logits[slot])
-                self._retire_if_done(slot, req, nxt, finished)
-                advanced.add(slot)
-        self._c_decode_ticks.inc()
-        if starved - advanced:
-            self._c_starved.inc(len(starved - advanced))
-        return finished
+        return toks, occupied
+
+    def _prepare_pages(self) -> None:
+        """Make each live slot's write page real and private before
+        the jitted tick: allocate on-demand past the prompt, COW-fork
+        shared pages (device page copy), then push the decided table."""
+        from ..runtime import executor
+        copies = []
+        for slot in self.live:
+            c = self._pool.prepare_decode(slot, self._slot_len[slot])
+            if c is not None:
+                copies.append(c)
+                self.obs.flight.event("cow_fork", slot=slot,
+                                      src_page=int(c[0]),
+                                      dst_page=int(c[1]))
+        executor.sync_page_table(self.state, self.program, self._pool)
+        if copies:
+            executor.apply_page_copies(self.state, self.program, copies)
+            self._c_cow_forks.inc(len(copies))
 
     def _spec_tick(self, toks: np.ndarray, occupied: np.ndarray,
                    finished: list) -> set:
@@ -1049,14 +1078,14 @@ class ServingEngine:
         """One engine tick: admit, decode one token for all live slots,
         retire finished requests.  Returns requests finished this tick.
 
-        Every tick is timed onto the ``tick_ms`` histogram and — when a
-        flight recorder is attached — lands one ``tick`` snapshot event
-        (live slots, queue depth, free pages, cumulative starved
-        ticks), the engine-level heartbeat the flight replay and the
-        console dashboard read."""
-        t0 = self.obs.clock()
-        finished = self._step_inner()
-        dt_ms = (self.obs.clock() - t0) * 1e3
+        Every tick is a ``serving.step`` span, whose duration feeds the
+        ``tick_ms`` histogram, and — when a flight recorder is attached
+        — lands one ``tick`` snapshot event (live slots, queue depth,
+        free pages, cumulative starved ticks), the engine-level
+        heartbeat the flight replay and the console dashboard read."""
+        with self.obs.span("serving.step") as tick:
+            finished = self._step_inner()
+        dt_ms = tick.seconds * 1e3
         self._tick_no += 1
         self._h_tick.observe(dt_ms)
         qd = len(self.admission) if self._lm_program else len(self.queue)

@@ -207,7 +207,7 @@ def test_event_taxonomy_is_closed():
     assert set(EVENT_FIELDS) >= {
         "enqueue", "admission", "prefill_start", "prefill_chunk",
         "first_token", "token", "spec", "cow_fork", "release", "tick",
-        "fallback", "op_sample"}
+        "fallback"}
 
 
 # --- engine integration ------------------------------------------------------------
@@ -276,32 +276,12 @@ def test_engine_flight_replay_matches_token_streams(tmp_path):
 
 
 def test_disabled_mode_zero_events_no_sampler():
-    """Default Observability: NULL recorder accumulates nothing, and
-    the op sampler is never constructed (no per-tick trace work)."""
+    """Default Observability: NULL recorder accumulates nothing."""
     eng, reqs, done = _run_engine()            # default obs
     assert eng.obs.flight is NULL
     assert eng.obs.flight.events == ()
     assert not eng.obs.flight_enabled
-    assert eng._op_sampler is None
     assert sum(len(r.out_tokens) for r in done) == 12
-
-
-def test_op_sampler_cadence_and_metrics():
-    """sample_ops_every=N: ~1/N decode ticks run the Stage-7 eager
-    trace; op_time_us{kind} histograms fill, and the sampled walk does
-    not perturb the engine's outputs (parity vs the unsampled run)."""
-    base_eng, _, base_done = _run_engine()
-    obs = Observability(sample_ops_every=2)
-    eng, reqs, done = _run_engine(obs=obs)
-    assert eng._op_sampler is not None
-    assert eng._op_sampler.n_samples >= 1
-    snap = obs.registry.snapshot()
-    op_keys = [k for k in snap["histograms"] if k.startswith("op_time_us")]
-    assert op_keys, "no op_time_us histograms recorded"
-    assert any("decode_attention" in k for k in op_keys)
-    # sampling is observation, not intervention
-    assert [r.out_tokens for r in done] == \
-        [r.out_tokens for r in base_done]
 
 
 def test_admission_counters_on_registry():
