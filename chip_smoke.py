@@ -149,7 +149,7 @@ def kernels(seed: int) -> None:
                                flash_attention, mamba2_scan, mamba2_scan_ref,
                                matmul, matmul_ref, maxpool2d_ref,
                                paged_decode_attention, wkv6, wkv6_ref)
-    from repro.kernels.decode_attention import gather_pages
+    from repro.kernels.decode_attention import fold_kv, gather_pages
 
     bf16, f32 = jnp.bfloat16, jnp.float32
     KW = dict(impl="pallas", interpret=False)
@@ -201,14 +201,18 @@ def kernels(seed: int) -> None:
         rnd((1, 15, 2048, 64), bf16), rnd((1, 5, 2048, 64), bf16),
         rnd((1, 5, 2048, 64), bf16))
 
-    # decode attention: 8 slots x 2048 cache rows, ragged lengths.
+    # decode attention: 8 slots x 2048 cache rows, ragged lengths, on the
+    # head-major cache and on the lane-dense one the KV regions hold.
     lens = jax.random.randint(next(keys), (8,), 1, 2049, jnp.int32)
-    run("decode_attention 8x2048",
-        lambda q, k, v, n: decode_attention(q, k, v, kv_len=n, **KW),
-        lambda q, k, v, n: decode_attention_ref(q, k, v, kv_len=n,
-                                                scale=64 ** -0.5),
-        rnd((8, 15, 64), bf16), rnd((8, 5, 2048, 64), bf16),
-        rnd((8, 5, 2048, 64), bf16), lens)
+    q, k, v = (rnd((8, 15, 64), bf16), rnd((8, 5, 2048, 64), bf16),
+               rnd((8, 5, 2048, 64), bf16))
+    for fold in (1, 2):
+        run(f"decode_attention 8x2048 fold={fold}",
+            lambda q, k, v, n, f=fold: decode_attention(
+                q, fold_kv(k, f), fold_kv(v, f), kv_len=n, fold=f, **KW),
+            lambda q, k, v, n: decode_attention_ref(q, k, v, kv_len=n,
+                                                    scale=64 ** -0.5),
+            q, k, v, lens)
 
     # paged decode at a small pool: 8 slots x 8 pages of 16 rows; float
     # and int8 pages.

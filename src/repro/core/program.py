@@ -81,6 +81,12 @@ class AttentionSpec:
       paged decode op the kv block IS the page (``block_kv ==
       page_size``, pinned by the tiling chooser) and the history is
       gathered through the op's page-table region.
+    * ``kv_fold`` — the lane-dense fold of the op's contiguous KV cache
+      regions (``regions.kv_cache_spec``): the regions hold
+      (slots, kv_heads, rows / kv_fold, kv_fold * head_dim).  None is
+      the row-major (slots, rows, kv_heads, head_dim) layout.  Copied
+      from the regions' record, so the executor never infers a layout
+      from a shape.
     """
 
     heads: int
@@ -92,6 +98,7 @@ class AttentionSpec:
     block_q: int = 128
     block_kv: int = 128
     page_size: int | None = None
+    kv_fold: int | None = None
 
 
 @dataclass(frozen=True)
@@ -201,7 +208,7 @@ class ProgramOp:
                      f"{' rope' if a.rope_theta else ''}")
             if self.k_cache_region is not None:
                 sched += (f" cache>r{self.k_cache_region},"
-                          f"r{self.v_cache_region}@slot")
+                          f"r{self.v_cache_region}@slot{_fold(a)}")
                 if self.page_table_region is not None:
                     sched += (f" pt=r{self.page_table_region}"
                               f" pg={self.attn.page_size}")
@@ -212,7 +219,7 @@ class ProgramOp:
                      f"{f' win={a.window}' if a.window else ''}"
                      f"{' rope' if a.rope_theta else ''}"
                      f" cache=r{self.k_cache_region},"
-                     f"r{self.v_cache_region}@pos")
+                     f"r{self.v_cache_region}@pos{_fold(a)}")
             if self.page_table_region is not None:
                 sched += f" pt=r{self.page_table_region} pg={a.page_size}"
                 if self.k_scale_region is not None:
@@ -241,6 +248,10 @@ class ProgramOp:
               if self.fuse_pool else "")])
         return (f"%{self.index:02d} {self.kernel:8s} {self.name:14s} "
                 f"{io:10s} {sched}{epi}")
+
+
+def _fold(a: AttentionSpec) -> str:
+    return "" if a.kv_fold is None else f" fold={a.kv_fold}"
 
 
 @dataclass(frozen=True)
@@ -467,7 +478,7 @@ def lower_to_program(graph: ModelGraph, schedule: ModelSchedule,
             # plan's allocator-owned persistent table (shared across a
             # prefill/decode pair).
             k_cache = v_cache = None
-            page_table = k_scale = v_scale = None
+            page_table = k_scale = v_scale = kv_fold = None
             if node.meta.get("k_cache") is not None:
                 k_cache = plan.persistent[node.meta["k_cache"]]
                 v_cache = plan.persistent[node.meta["v_cache"]]
@@ -479,6 +490,8 @@ def lower_to_program(graph: ModelGraph, schedule: ModelSchedule,
                     if node.meta.get("k_scale") is not None:
                         k_scale = plan.persistent[node.meta["k_scale"]]
                         v_scale = plan.persistent[node.meta["v_scale"]]
+                else:
+                    kv_fold = plan.region(k_cache).fold
             ops.append(ProgramOp(
                 kernel=("decode_attention" if node.meta.get("decode")
                         else "flash_attention"),
@@ -495,7 +508,8 @@ def lower_to_program(graph: ModelGraph, schedule: ModelSchedule,
                     rope_theta=node.meta.get("rope_theta", 0.0),
                     block_q=ls.notes.get("block_q", 128),
                     block_kv=ls.notes.get("block_kv", 128),
-                    page_size=ls.notes.get("page_size")),
+                    page_size=ls.notes.get("page_size"),
+                    kv_fold=kv_fold),
                 **common))
         elif node.kind is LayerKind.MOE:
             # Capacity-bucketed expert dispatch (§6 load balancing):

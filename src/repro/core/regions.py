@@ -59,7 +59,8 @@ from .ir import ModelGraph
 __all__ = ["Region", "RegionPlan", "PersistentSpec", "PagedPlan",
            "StateCaps", "allocate_regions", "extend_with_persistent",
            "paged_kv_specs", "pages_for_len", "register_state_family",
-           "state_specs", "PAGE_TABLE_REGION"]
+           "state_specs", "PAGE_TABLE_REGION", "kv_fold", "kv_cache_spec",
+           "kv_cache_rows"]
 
 N_PINGPONG = 2          # the paper's sequential double-buffer pair
 
@@ -74,6 +75,7 @@ class Region:
     name: str | None = None
     shape: tuple | None = None
     dtype: str | None = None     # numpy dtype name ("float32", "bfloat16")
+    fold: int | None = None      # lane-dense KV fold (PersistentSpec.fold)
 
 
 @dataclass(frozen=True)
@@ -86,6 +88,10 @@ class PersistentSpec:
     for cross-attention.  ``read_only`` marks state the decode stream
     only ever reads (encoder memory written once at admission); the
     executor never scatters into such a region and tests pin that.
+    ``fold`` is set on a self-attention KV region stored lane-dense
+    (``kv_cache_spec``): the rows fold ``fold`` at a time into
+    (slots, kv_heads, rows / fold, fold * head_dim).  None is the
+    row-major (slots, rows, kv_heads, head_dim) layout.
     """
 
     name: str
@@ -93,6 +99,7 @@ class PersistentSpec:
     dtype: str                   # numpy dtype name
     size_bytes: int
     read_only: bool = False
+    fold: int | None = None
 
 
 @dataclass(frozen=True)
@@ -290,9 +297,52 @@ def extend_with_persistent(plan: RegionPlan, specs: tuple,
         persistent[spec.name] = rid
         extra.append(Region(rid, "persistent", int(spec.size_bytes),
                             name=spec.name, shape=tuple(spec.shape),
-                            dtype=spec.dtype))
+                            dtype=spec.dtype, fold=spec.fold))
     return replace(plan, regions=plan.regions + tuple(extra),
                    persistent=persistent)
+
+
+# --- lane-dense KV regions --------------------------------------------------------
+LANES = 128             # the minor (lane) extent of a TPU vector tile
+
+
+def kv_fold(rows: int, head_dim: int) -> int | None:
+    """How many cache rows one lane-dense row of a KV region holds, or
+    None where ``head_dim`` neither divides nor is a multiple of 128.
+
+    A region at rest takes the layout XLA gives its shape: a minor dim
+    under 128 lanes pads to 128, so XLA keeps a (rows, kv_heads, 64)
+    region rows-minor and copies it whole into the row-major operand
+    the decode kernel wants, every tick.  Folding ``f = 128 / head_dim``
+    consecutive rows into one 128-lane row makes the minor dim dense,
+    so the region rests row-major, as the kernel reads it.  A short
+    region folds by the largest power of two dividing its rows."""
+    if head_dim % LANES == 0:
+        return 1
+    if LANES % head_dim:
+        return None
+    return math.gcd(LANES // head_dim, rows)
+
+
+def kv_cache_spec(name: str, slots: int, rows: int, kv_heads: int,
+                  head_dim: int, dtype) -> PersistentSpec:
+    """THE shape rule of a self-attention KV region, shared by every
+    family the decode-attention op serves: (slots, kv_heads, rows / f,
+    f * head_dim) with ``f = kv_fold(rows, head_dim)``, recorded on the
+    spec so that the executor never infers the layout from a shape;
+    (slots, rows, kv_heads, head_dim) where there is no fold."""
+    from jax import numpy as jnp          # bfloat16/float8 dtype names
+    dt = jnp.dtype(dtype)
+    f = kv_fold(rows, head_dim)
+    shape = ((slots, rows, kv_heads, head_dim) if f is None
+             else (slots, kv_heads, rows // f, f * head_dim))
+    return PersistentSpec(name, shape, dt.name,
+                          math.prod(shape) * dt.itemsize, fold=f)
+
+
+def kv_cache_rows(shape: tuple, fold: int | None) -> int:
+    """Real cache rows per slot of a KV region of ``shape``."""
+    return shape[1] if fold is None else shape[2] * fold
 
 
 # --- paged KV plan (§5.1 third scheme: ping-pong, rolling-ring, paged) -------------

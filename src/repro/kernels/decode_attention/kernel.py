@@ -10,6 +10,17 @@ operand).
 Grid: (B * Hq, S / bkv), kv innermost with running-softmax scratch.
 GQA folded into the KV index map as in flash_attention.
 
+Lane-dense cache: the cache arrives as it rests in its persistent
+region, (B, Hkv, S / f, f * D) with ``f`` consecutive rows folded into
+one row of ``f * D`` lanes (``core/regions.py::kv_fold``; f = 1 is
+plain head-major).  Each grid step streams a (bkv / f, f * D) block and
+scores it against ``f`` query rows at once: query row ``p`` holds q in
+lane group ``p`` and zeros elsewhere, so score ``(p, j)`` is real cache
+row ``f * j + p``.  The f rows share one running max and sum; lane
+group ``p`` of accumulator row ``p`` is that parity's share of the
+output, and the groups are summed by lane rotation when the last block
+has been read.
+
 Ring-cache semantics: the per-sequence ``kv_len`` (see
 ops.py::ring_kv_len) bounds the valid rows of a rolling cache — blocks
 whose start is past ``kv_len`` are skipped entirely (``sk0 < kv_len``
@@ -33,18 +44,21 @@ NEG_INF = -1e30
 
 
 def _online_softmax_step(q, k, v, valid, m_ref, l_ref, acc_ref, scale):
-    """Fold one kv block into the running softmax state.  q: (1, D);
-    k, v: (bkv, D) f32; valid: (1, bkv) row mask."""
+    """Fold one kv block into the running softmax state.  q: (f, W)
+    query rows; k, v: (R, W) f32; valid: (f, R) row mask.  One max and
+    one sum are shared by all f query rows."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_cur = jnp.max(jnp.max(s, axis=-1, keepdims=True), axis=0,
+                    keepdims=True)
     m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
     alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
     p = jnp.exp(s - m_new[:, :1])
-    l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
-        p.sum(-1, keepdims=True), l_ref.shape)
+    p_sum = jnp.sum(jnp.sum(p, axis=-1, keepdims=True), axis=0,
+                    keepdims=True)
+    l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(p_sum, l_ref.shape)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -57,13 +71,27 @@ def _init_state(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def _emit(o_ref, l_ref, acc_ref):
+def _emit(o_ref, l_ref, acc_ref, fold: int = 1):
+    """Normalise and store the (1, D) output.  With f > 1 the output is
+    lane group ``p`` of accumulator row ``p``, summed over ``p``: mask
+    the other groups, sum the rows, then add the f - 1 lane rotations
+    so that lanes [0, D) hold the sum of every group."""
+    acc = acc_ref[...]
+    if fold > 1:
+        W = acc.shape[1]
+        D = W // fold
+        lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        acc = jnp.sum(jnp.where(lane // D == row, acc, 0.0), axis=0,
+                      keepdims=True)
+        acc = acc + sum(pltpu.roll(acc, p * D, 1) for p in range(1, fold))
+        acc = acc[:, :D]
     l = jnp.maximum(l_ref[:, :1], 1e-30)
-    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def _body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-          scale, bkv, Hq):
+          scale, bkv, Hq, fold):
     kb = pl.program_id(1)
     nkv = pl.num_programs(1)
 
@@ -76,7 +104,9 @@ def _body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(sk0 < kv_len)
     def _compute():
-        ki = sk0 + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+        shape = (fold, bkv // fold)
+        ki = (sk0 + fold * jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+              + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
         _online_softmax_step(q_ref[0].astype(jnp.float32),
                              k_ref[0].astype(jnp.float32),
                              v_ref[0].astype(jnp.float32), ki < kv_len,
@@ -84,43 +114,52 @@ def _body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(kb == nkv - 1)
     def _finish():
-        _emit(o_ref, l_ref, acc_ref)
+        _emit(o_ref, l_ref, acc_ref, fold)
 
 
-def _state_scratch(D):
+def _state_scratch(rows, W):
     return [vmem_scratch((1, 128), jnp.float32),
             vmem_scratch((1, 128), jnp.float32),
-            vmem_scratch((1, D), jnp.float32)]
+            vmem_scratch((rows, W), jnp.float32)]
 
 
 def decode_attention_pallas(q, k, v, kv_len, *, scale: float,
-                            block_kv: int = 1024,
+                            block_kv: int = 1024, fold: int = 1,
                             interpret: bool | None = None) -> jax.Array:
-    """q: (B, Hq, D); k, v: (B, Hkv, S, D); kv_len: (B,) int32, handed
-    to the kernel as a scalar-prefetch operand (it lives in SMEM)."""
+    """q: (B, Hq, D); k, v: (B, Hkv, S / fold, fold * D), the lane-dense
+    cache as it rests (fold 1: plain (B, Hkv, S, D)); kv_len: (B,)
+    int32 valid real rows, handed to the kernel as a scalar-prefetch
+    operand (it lives in SMEM).  ``block_kv`` counts real rows."""
     if interpret is None:
         interpret = default_interpret()
     B, Hq, D = q.shape
-    _, Hkv, S, _ = k.shape
+    _, Hkv, Sf, W = k.shape
+    assert W == fold * D, (k.shape, fold, D)
+    S = Sf * fold
     group = Hq // Hkv
     bkv = min(block_kv, S)
-    assert S % bkv == 0
+    assert S % bkv == 0 and bkv % fold == 0
 
     qf = q.reshape(B * Hq, 1, D)
-    kf = k.reshape(B * Hkv, S, D)
-    vf = v.reshape(B * Hkv, S, D)
+    if fold > 1:
+        # Query row p: q in lane group p, zeros elsewhere.
+        groups = (jnp.arange(W)[None] // D) == jnp.arange(fold)[:, None]
+        qf = jnp.where(groups[None], jnp.tile(qf, (1, 1, fold)), 0)
+    kf = k.reshape(B * Hkv, Sf, W)
+    vf = v.reshape(B * Hkv, Sf, W)
 
     def kv_map(h, kb, lens):
         return ((h // Hq) * Hkv + (h % Hq) // group, kb, 0)
 
-    row = pl.BlockSpec((1, 1, D), lambda h, kb, lens: (h, 0, 0))
+    kv_block = pl.BlockSpec((1, bkv // fold, W), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(B * Hq, S // bkv),
-        in_specs=[row, pl.BlockSpec((1, bkv, D), kv_map),
-                  pl.BlockSpec((1, bkv, D), kv_map)],
-        out_specs=row, scratch_shapes=_state_scratch(D))
+        in_specs=[pl.BlockSpec((1, fold, W), lambda h, kb, lens: (h, 0, 0)),
+                  kv_block, kv_block],
+        out_specs=pl.BlockSpec((1, 1, D), lambda h, kb, lens: (h, 0, 0)),
+        scratch_shapes=_state_scratch(fold, W))
     out = pl.pallas_call(
-        functools.partial(_body, scale=scale, bkv=bkv, Hq=Hq),
+        functools.partial(_body, scale=scale, bkv=bkv, Hq=Hq, fold=fold),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, D), q.dtype),
         interpret=interpret,
@@ -214,7 +253,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pf, grid=(B * Hq, pages_per_slot),
         in_specs=[row, whole_pool, whole_pool], out_specs=row,
-        scratch_shapes=_state_scratch(D))
+        scratch_shapes=_state_scratch(1, D))
     out = pl.pallas_call(
         functools.partial(_paged_body, scale=scale, page_size=page_size,
                           Hq=Hq, Hkv=Hkv, quant=quant),

@@ -23,7 +23,23 @@ from .kernel import decode_attention_pallas, paged_decode_attention_pallas
 from .ref import decode_attention_ref
 
 __all__ = ["decode_attention", "paged_decode_attention", "gather_pages",
-           "ring_kv_len", "ring_positions"]
+           "ring_kv_len", "ring_positions", "fold_kv", "unfold_kv"]
+
+
+def fold_kv(x, fold: int):
+    """(..., S, D) -> (..., S / fold, fold * D): row ``fold * j + p``
+    moves to packed row ``j``, lanes ``[p * D, (p + 1) * D)`` — the
+    lane-dense layout of a persistent KV region
+    (``core/regions.py::kv_cache_spec``).  A row-major reshape, so it
+    costs nothing on head-major (..., S, D) data."""
+    *lead, S, D = x.shape
+    return x.reshape(*lead, S // fold, fold * D)
+
+
+def unfold_kv(x, fold: int):
+    """Inverse of ``fold_kv``: (..., S / fold, fold * D) -> (..., S, D)."""
+    *lead, Sf, W = x.shape
+    return x.reshape(*lead, Sf * fold, W // fold)
 
 
 def ring_positions(length, cache_len: int, seq_len: int):
@@ -66,17 +82,20 @@ def ring_kv_len(pos, cache_len: int):
 
 def decode_attention(q, k, v, *, kv_len=None, scale: float | None = None,
                      impl: str = "auto", block_kv: int | None = None,
-                     hw: HardwareModel = TPU_V5E,
+                     fold: int = 1, hw: HardwareModel = TPU_V5E,
                      interpret: bool | None = None) -> jax.Array:
-    """Single-token decode: q (B,Hq,D) vs cache (B,Hkv,S,D)."""
+    """Single-token decode: q (B,Hq,D) vs cache (B,Hkv,S/fold,fold*D),
+    the lane-dense layout ``fold_kv`` makes (fold 1: (B,Hkv,S,D))."""
     B, Hq, D = q.shape
-    S = k.shape[2]
+    S = k.shape[2] * fold
     scale = scale if scale is not None else D ** -0.5
     if kv_len is None:
         kv_len = jnp.full((B,), S, jnp.int32)
     impl = resolve_impl(impl)
     if impl == "reference":
-        return decode_attention_ref(q, k, v, kv_len=kv_len, scale=scale)
+        return decode_attention_ref(q, unfold_kv(k, fold),
+                                    unfold_kv(v, fold), kv_len=kv_len,
+                                    scale=scale)
     if block_kv is None:
         # T2 decode regime: cache block sized to stream at full
         # bandwidth, k+v double buffered.  One chooser shared with the
@@ -86,12 +105,13 @@ def decode_attention(q, k, v, *, kv_len=None, scale: float | None = None,
         # cache is already window-sized, so S is the right extent.
         from ...core.tiling import select_attention_blocks
         _, block_kv = select_attention_blocks(1, S, D, k.dtype.itemsize, hw)
-    pad = (-S) % block_kv
+    pad = ((-S) % block_kv) // fold
     if pad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     return decode_attention_pallas(q, k, v, kv_len, scale=scale,
-                                   block_kv=block_kv, interpret=interpret)
+                                   block_kv=block_kv, fold=fold,
+                                   interpret=interpret)
 
 
 def gather_pages(pages, table, scale=None):

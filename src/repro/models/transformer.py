@@ -24,8 +24,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-import numpy as np
-
 from ..configs.base import ArchConfig
 from ..core.hw import TPU_V5E, HardwareModel
 from ..core.ir import (ModelGraph, attention_node, decode_attention_node,
@@ -34,8 +32,8 @@ from ..core.ir import (ModelGraph, attention_node, decode_attention_node,
 from ..core.program import Program, ProgramPair, lower_to_program
 from ..core.regions import (PAGE_TABLE_REGION, PersistentSpec, StateCaps,
                             allocate_regions, extend_with_persistent,
-                            paged_kv_specs, register_state_family,
-                            state_specs)
+                            kv_cache_spec, paged_kv_specs,
+                            register_state_family, state_specs)
 from ..core.schedule import compile_model
 from ..kernels.decode_attention import (decode_attention, ring_kv_len,
                                         ring_positions)
@@ -628,19 +626,16 @@ def to_decode_graph(cfg: ArchConfig, slots: int = 8, max_len: int = 256,
 
 def _kv_cache_specs(cfg: ArchConfig, slots: int,
                     max_len: int) -> tuple[PersistentSpec, ...]:
-    """One persistent (slots, kv_cache_len, kv_heads, head_dim) region
-    per block and cache side, in the engine's KV dtype.  A sliding
-    window shrinks the resident rows to the window (max_len/W fewer
-    persistent KV bytes), the §5.1 sizing rule applied to state."""
-    KV, hd = cfg.n_kv_heads, cfg.hd
-    dt = jnp.dtype(cfg.kv_jdtype)
-    shape = (slots, kv_cache_len(cfg, max_len), KV, hd)
-    size = int(np.prod(shape)) * dt.itemsize
-    specs = []
-    for i in range(cfg.n_layers):
-        specs.append(PersistentSpec(f"l{i}.k_cache", shape, dt.name, size))
-        specs.append(PersistentSpec(f"l{i}.v_cache", shape, dt.name, size))
-    return tuple(specs)
+    """One persistent KV region of kv_cache_len rows per block and cache
+    side, in the engine's KV dtype and the lane-dense layout of
+    ``regions.kv_cache_spec``.  A sliding window shrinks the resident
+    rows to the window (max_len/W fewer persistent KV bytes), the §5.1
+    sizing rule applied to state."""
+    rows = kv_cache_len(cfg, max_len)
+    return tuple(
+        kv_cache_spec(f"l{i}.{side}", slots, rows, cfg.n_kv_heads, cfg.hd,
+                      cfg.kv_jdtype)
+        for i in range(cfg.n_layers) for side in ("k_cache", "v_cache"))
 
 
 # Generic named-state hooks (regions.state_specs).  Dense KV state

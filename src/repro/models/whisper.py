@@ -20,7 +20,7 @@ from ..configs.base import ArchConfig
 from ..core.ir import (ModelGraph, attention_node, cross_attention_node,
                        decode_attention_node, embed_node, matmul_node,
                        norm_node)
-from ..core.regions import (PersistentSpec, StateCaps,
+from ..core.regions import (PersistentSpec, StateCaps, kv_cache_spec,
                             register_state_family)
 from ..kernels.decode_attention import decode_attention
 from ..parallel.act_sharding import shard_act
@@ -411,17 +411,15 @@ def _audio_state_specs(cfg: ArchConfig, slots: int, max_len: int):
     them before the first decoder row computes (not chunkable)."""
     KV, hd = cfg.n_kv_heads, cfg.hd
     kdt = jnp.dtype(cfg.kv_jdtype)
-    Te = cfg.encoder_seq
+    shape = (slots, cfg.encoder_seq, KV, hd)
     specs = []
     for i in range(cfg.n_layers):
-        for side, rows, ro in (("k_cache", max_len, False),
-                               ("v_cache", max_len, False),
-                               ("cross_k", Te, True),
-                               ("cross_v", Te, True)):
-            shape = (slots, rows, KV, hd)
-            specs.append(PersistentSpec(
-                f"l{i}.{side}", shape, kdt.name,
-                int(np.prod(shape)) * kdt.itemsize, read_only=ro))
+        specs += [kv_cache_spec(f"l{i}.{side}", slots, max_len, KV, hd, kdt)
+                  for side in ("k_cache", "v_cache")]
+        specs += [PersistentSpec(f"l{i}.{side}", shape, kdt.name,
+                                 int(np.prod(shape)) * kdt.itemsize,
+                                 read_only=True)
+                  for side in ("cross_k", "cross_v")]
     return tuple(specs), StateCaps()
 
 

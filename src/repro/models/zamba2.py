@@ -19,7 +19,8 @@ from ..configs.base import ArchConfig
 from ..core.ir import (ModelGraph, attention_node, decode_attention_node,
                        elementwise_node, embed_node, matmul_node, norm_node,
                        ssm_scan_node)
-from ..core.regions import PersistentSpec, StateCaps, register_state_family
+from ..core.regions import (PersistentSpec, StateCaps, kv_cache_spec,
+                            register_state_family)
 from ..kernels.mamba2 import mamba2_decode_step, mamba2_scan
 from ..parallel.act_sharding import shard_act
 from .common import ParamDef, Rotary, rms_norm
@@ -482,14 +483,9 @@ def _hybrid_state_specs(cfg: ArchConfig, slots: int, max_len: int):
     dt = jnp.dtype(cfg.jdtype)
     kdt = jnp.dtype(cfg.kv_jdtype)
     W = min(max_len, cfg.attn_window) if cfg.attn_window else max_len
-    kv_shape = (slots, W, cfg.n_kv_heads, cfg.hd)
-    kv_size = int(np.prod(kv_shape)) * kdt.itemsize
-    specs = []
-    for a in range(_n_apps(cfg)):
-        specs.append(PersistentSpec(f"app{a}.k_cache", kv_shape, kdt.name,
-                                    kv_size))
-        specs.append(PersistentSpec(f"app{a}.v_cache", kv_shape, kdt.name,
-                                    kv_size))
+    specs = [kv_cache_spec(f"app{a}.{side}", slots, W, cfg.n_kv_heads,
+                           cfg.hd, kdt)
+             for a in range(_n_apps(cfg)) for side in ("k_cache", "v_cache")]
     s_shape = (slots, H, N, P)
     c_shape = (slots, _CONV_K - 1, di + 2 * N)
     for i in range(cfg.n_layers):

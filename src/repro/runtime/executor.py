@@ -53,11 +53,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.program import Program, ProgramOp, ProgramPair
-from ..core.regions import PAGE_TABLE_REGION, PagedPlan, pages_for_len
+from ..core.regions import (PAGE_TABLE_REGION, PagedPlan, kv_cache_rows,
+                            pages_for_len)
 from ..kernels.conv2d import avgpool2d_ref, conv2d, maxpool2d_ref
-from ..kernels.decode_attention import (decode_attention,
+from ..kernels.decode_attention import (decode_attention, fold_kv,
                                         paged_decode_attention, ring_kv_len,
-                                        ring_positions)
+                                        ring_positions, unfold_kv)
 from ..kernels.flash_attention import flash_attention
 from ..kernels.matmul import matmul
 
@@ -356,9 +357,12 @@ class ProgramState:
     """Runtime carrier for a Program pair's persistent regions.
 
     ``caches`` maps the allocator's persistent region ids to their
-    buffers — for the LM pair, (slots, cache_len, kv_heads, head_dim)
-    per block and cache side, where cache_len is max_len or the
-    attention window (whichever the §5.1 plan sized the region at);
+    buffers — for the LM pair, one KV region per block and cache side
+    in the layout ``regions.kv_cache_spec`` gives it (lane-dense
+    (slots, kv_heads, cache_len / f, f * head_dim), or (slots,
+    cache_len, kv_heads, head_dim) where head_dim does not fold), where
+    cache_len is max_len or the attention window (whichever the §5.1
+    plan sized the region at);
     ``lengths`` is the per-slot sequence length (the decode ops'
     position operand, counting absolute tokens even once the ring has
     wrapped).  Registered as a pytree
@@ -400,10 +404,24 @@ def init_program_state(pair: ProgramPair | Program) -> ProgramState:
     return ProgramState(caches, jnp.zeros((slots,), jnp.int32))
 
 
+def _to_region(rows: jax.Array, fold: int | None) -> jax.Array:
+    """Head-major (..., KV, S, hd) rows in a KV region's layout: folded
+    lane-dense (a free reshape), or row-major (..., S, KV, hd)."""
+    return jnp.swapaxes(rows, -3, -2) if fold is None else fold_kv(rows, fold)
+
+
+def _from_region(buf: jax.Array, fold: int | None) -> jax.Array:
+    """Inverse of ``_to_region``: a KV region's rows as (..., KV, S, hd)."""
+    return jnp.swapaxes(buf, -3, -2) if fold is None else unfold_kv(buf, fold)
+
+
 def _write_prefill_cache(caches: dict, op: ProgramOp, k, v, slot,
                          length) -> None:
     """Store a prefill op's per-head K/V — (1, KVh, S, hd) — into the
-    (slots, cache_len, KV, hd) cache regions at the admitted slot.
+    cache regions at the admitted slot, in the regions' layout
+    (``op.attn.kv_fold``).  The rows start at row 0 and are whole, so
+    into a lane-dense region the write is a reshape of the K/V as the
+    prefill computed them.
 
     A window-sized region (cache_len < S, the §5.1 rolling-window plan)
     receives the **ring layout** the decode ops expect, via the shared
@@ -415,14 +433,15 @@ def _write_prefill_cache(caches: dict, op: ProgramOp, k, v, slot,
     by decode before ``ring_kv_len`` ever admits them), so re-admission
     into a previously used slot can never leak a dead request's stale
     rows."""
+    fold = op.attn.kv_fold
     for rid, val in ((op.k_cache_region, k), (op.v_cache_region, v)):
         buf = caches[rid]
-        row = val[0].transpose(1, 0, 2).astype(buf.dtype)     # (S, KV, hd)
-        S, cache_len = row.shape[0], buf.shape[1]
+        rows = val[0].astype(buf.dtype)                       # (KV, S, hd)
+        S, cache_len = rows.shape[1], kv_cache_rows(buf.shape, fold)
         if cache_len < S:
-            row = row[ring_positions(length, cache_len, S)]
+            rows = rows[:, ring_positions(length, cache_len, S)]
         caches[rid] = jax.lax.dynamic_update_slice(
-            buf, row[None], (slot, 0, 0, 0))
+            buf, _to_region(rows, fold)[None], (slot, 0, 0, 0))
 
 
 def _write_prefill_cache_paged(caches: dict, op: ProgramOp, k, v, slot,
@@ -544,18 +563,19 @@ def _run_attention_chunk(op: ProgramOp, regions: dict, caches: dict,
         page = jnp.take_along_axis(pt_rows, pos[None] // pg, axis=1)
         hk = caches[op.k_cache_region][page, pos[None] % pg]
         hv = caches[op.v_cache_region][page, pos[None] % pg]
+        hk, hv = hk.transpose(0, 2, 1, 3), hv.transpose(0, 2, 1, 3)
         valid = pos[None] < start[:, None]
     else:
         buf_k, buf_v = caches[op.k_cache_region], caches[op.v_cache_region]
-        cache_len = buf_k.shape[1]
+        cache_len = kv_cache_rows(buf_k.shape, a.kv_fold)
         ring = pos % cache_len
-        hk = buf_k[slot][:, ring]                      # (B, S, KV, hd)
-        hv = buf_v[slot][:, ring]
+        hk = _from_region(buf_k[slot], a.kv_fold)[:, :, ring]  # (B,KV,S,hd)
+        hv = _from_region(buf_v[slot], a.kv_fold)[:, :, ring]
         valid = ((pos[None] < start[:, None])
                  & (pos[None] >= start[:, None] - cache_len))
     m = valid[:, None, :, None]                        # (B, 1, S, 1)
-    k = jnp.where(m, hk.transpose(0, 2, 1, 3).astype(k.dtype), k)
-    v = jnp.where(m, hv.transpose(0, 2, 1, 3).astype(v.dtype), v)
+    k = jnp.where(m, hk.astype(k.dtype), k)
+    v = jnp.where(m, hv.astype(v.dtype), v)
     out = flash_attention(q, k, v, causal=a.causal, window=a.window,
                           block_q=a.block_q, block_kv=a.block_kv,
                           impl=impl, interpret=interpret)
@@ -566,8 +586,9 @@ def _run_attention_chunk(op: ProgramOp, regions: dict, caches: dict,
 def _write_chunk_cache(caches: dict, op: ProgramOp, k, v, slot, start,
                        stop, length) -> None:
     """Store a chunk's fresh K/V rows — (B, KVh, S, hd), rows ``[start,
-    stop)`` per batch entry — into the (slots, cache_len, KV, hd) cache
-    regions.
+    stop)`` per batch entry — into the cache regions.  The masks select
+    real rows on the regions' rows unfolded (``_from_region``), so in a
+    lane-dense region each row is one lane group of a packed row.
 
     Contiguous regions take the chunk rows in place; the final chunk
     (``stop == length``) extends the write through the padded tail so
@@ -579,28 +600,29 @@ def _write_chunk_cache(caches: dict, op: ProgramOp, k, v, slot, start,
     duplicate-early-row rule ``ring_positions`` applies for ring rows
     no prompt position covers — so re-admission hygiene and whole-
     prefill bit-parity both hold."""
+    fold = op.attn.kv_fold
     for rid, val in ((op.k_cache_region, k), (op.v_cache_region, v)):
         buf = caches[rid]
-        row = val.transpose(0, 2, 1, 3).astype(buf.dtype)   # (B, S, KV, hd)
-        S, cache_len = row.shape[1], buf.shape[1]
-        old = buf[slot]                                     # (B, cl, KV, hd)
+        row = val.astype(buf.dtype)                         # (B, KV, S, hd)
+        S, cache_len = row.shape[2], kv_cache_rows(buf.shape, fold)
+        old = _from_region(buf[slot], fold)                 # (B, KV, cl, hd)
         if cache_len == S:
             wstop = jnp.where(stop >= length, S, stop)
             pos = jnp.arange(S)
             m = (pos[None] >= start[:, None]) & (pos[None] < wstop[:, None])
-            new = jnp.where(m[..., None, None], row, old)
+            new = jnp.where(m[:, None, :, None], row, old)
         else:
             wstop = jnp.minimum(stop, length)
             j = jnp.arange(cache_len)
             last = (wstop - 1)[:, None]
             p = j[None] + ((last - j[None]) // cache_len) * cache_len
             written = (p >= start[:, None]) & (p < wstop[:, None])
-            gathered = jax.vmap(lambda r, idx: r[idx])(
+            gathered = jax.vmap(lambda r, idx: r[:, idx])(
                 row, jnp.clip(p, 0, S - 1))
-            seed = jnp.broadcast_to(row[:, :1], old.shape)
+            seed = jnp.broadcast_to(row[:, :, :1], old.shape)
             base = jnp.where((start == 0)[:, None, None, None], seed, old)
-            new = jnp.where(written[..., None, None], gathered, base)
-        caches[rid] = buf.at[slot].set(new)
+            new = jnp.where(written[:, None, :, None], gathered, base)
+        caches[rid] = buf.at[slot].set(_to_region(new, fold))
 
 
 def _write_chunk_cache_paged(caches: dict, op: ProgramOp, k, v, slot,
@@ -706,6 +728,24 @@ def jitted_chunk_runner(program: Program, impl: str = "auto",
     return _cached_runner((id(program), impl, interpret, "chunk"), make)
 
 
+def _write_decode_rows(buf, rows, live, at, window) -> jax.Array:
+    """Scatter each slot's new (KV, hd) row into its slot of a KV
+    region, at offsets ``at`` (three (slots,) index vectors) in a
+    ``window``-shaped block of the slot.  A dead slot's index points
+    one past the last slot, so its write is dropped: its rows stay as
+    they were without the old row being read, and the scatter updates
+    the region in place.  (Reading the old rows back, a per-slot
+    gather, makes XLA copy the whole region into another layout.)"""
+    slots = buf.shape[0]
+    idx = jnp.stack([jnp.where(live, jnp.arange(slots), slots), *at], axis=1)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2, 3), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 1, 2, 3))
+    return jax.lax.scatter(
+        buf, idx, rows.reshape((slots,) + window).astype(buf.dtype), dims,
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
 def _run_decode_attention(op: ProgramOp, src: jax.Array, k_src: jax.Array,
                           v_src: jax.Array, ck: jax.Array, cv: jax.Array,
                           pos: jax.Array, live: jax.Array, *, impl: str,
@@ -716,7 +756,12 @@ def _run_decode_attention(op: ProgramOp, src: jax.Array, k_src: jax.Array,
     over the ring-valid rows.  Returns (out (B, heads*head_dim),
     new_k_cache, new_v_cache).  Shared verbatim by ``run_decode`` and
     the replay harness, so a replayed op cannot drift from the
-    executor."""
+    executor.
+
+    A lane-dense region (``op.attn.kv_fold``) is handed to the kernel as
+    it rests, and row ``r`` is written in place at packed row ``r // f``,
+    lanes ``[(r % f) * hd, ...)``.  A row-major region is transposed to
+    the kernel's head-major operand first: a whole-cache copy."""
     from ..models.common import Rotary, apply_rope
     a = op.attn
     B = src.shape[0]
@@ -727,28 +772,21 @@ def _run_decode_attention(op: ProgramOp, src: jax.Array, k_src: jax.Array,
         cos, sin = Rotary(a.head_dim, a.rope_theta).freqs(pos)
         q = apply_rope(q, cos[:, None], sin[:, None])
         k_new = apply_rope(k_new, cos[:, None], sin[:, None])
-    cache_len = ck.shape[1]
+    f, KV, hd = a.kv_fold, a.kv_heads, a.head_dim
+    cache_len = kv_cache_rows(ck.shape, f)
     row = pos % cache_len                 # rolling overwrite
-
-    def cur(c, r):
-        return jax.lax.dynamic_slice_in_dim(c, r, 1, axis=0)[0]
-
-    def upd(c, x, r):
-        return jax.lax.dynamic_update_slice_in_dim(c, x[None], r, axis=0)
-
-    # Mask the *row*, not the buffer: a dead slot rewrites its
-    # current row with itself (a no-op), so the select stays
-    # row-sized and the bandwidth-bound cache update remains a
-    # single in-place scatter per side.
-    keep = live[:, None, None]
-    k_row = jnp.where(keep, k_new.astype(ck.dtype), jax.vmap(cur)(ck, row))
-    v_row = jnp.where(keep, v_new.astype(cv.dtype), jax.vmap(cur)(cv, row))
-    ck = jax.vmap(upd)(ck, k_row, row)
-    cv = jax.vmap(upd)(cv, v_row, row)
+    zero = jnp.zeros_like(row)
+    if f is None:                         # a slot's (cache_len, KV, hd)
+        at, window = (row, zero, zero), (1, KV, hd)
+    else:                                 # a slot's (KV, cache_len/f, f*hd)
+        at, window = (zero, row // f, (row % f) * hd), (KV, 1, hd)
+    ck = _write_decode_rows(ck, k_new, live, at, window)
+    cv = _write_decode_rows(cv, v_new, live, at, window)
+    k_in, v_in = ((ck, cv) if f is not None
+                  else (ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3)))
     out = decode_attention(
-        q, ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3),
-        kv_len=ring_kv_len(pos, cache_len), block_kv=a.block_kv,
-        impl=impl, interpret=interpret)
+        q, k_in, v_in, kv_len=ring_kv_len(pos, cache_len),
+        block_kv=a.block_kv, fold=f or 1, impl=impl, interpret=interpret)
     return out.reshape(B, a.heads * a.head_dim), ck, cv
 
 
@@ -1193,7 +1231,7 @@ def _op_schedule(op: ProgramOp) -> dict:
                      "head_dim": a.head_dim, "causal": a.causal,
                      "window": a.window, "rope_theta": a.rope_theta,
                      "block_q": a.block_q, "block_kv": a.block_kv,
-                     "page_size": a.page_size}
+                     "page_size": a.page_size, "kv_fold": a.kv_fold}
     return d
 
 

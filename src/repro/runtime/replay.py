@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from ..core.dataflow import Dataflow
 from ..core.program import AttentionSpec, ProgramOp
+from ..core.regions import kv_cache_rows
 from ..core.tiling import ConvTiling
 from .executor import (_FAMILY_KERNELS, TraceRecord,
                        _run_decode_attention, _run_op, _time_thunk)
@@ -188,8 +189,9 @@ def replay_record(record: TraceRecord | dict, *,
     op = op_from_record(r, candidate)
     regions, params = synth_operands(r, seed)
     if r.kind == "decode_attention":
-        slots = r.operands["k_cache"][0][0]
-        cache_len = r.operands["k_cache"][0][1]
+        shape = r.operands["k_cache"][0]
+        slots = shape[0]
+        cache_len = kv_cache_rows(shape, op.attn.kv_fold)
         pos = jnp.asarray(r.extras.get("pos", [cache_len // 2] * slots),
                           jnp.int32)
         live = jnp.asarray(r.extras.get("live", [True] * slots), bool)

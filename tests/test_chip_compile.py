@@ -12,7 +12,9 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and each
 test worker imports every test file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,11 +93,61 @@ def test_flash_attention_bwd_compiles(one_chip):
              ((2, 5, 2048, 64), BF16))
 
 
-def test_decode_attention_compiles(one_chip):
-    # 8 slots x 2048 cache rows at smollm-360m widths.
-    _compile(lambda q, k, v, n: decode_attention(q, k, v, kv_len=n, **KW),
-             one_chip, ((8, 15, 64), BF16), ((8, 5, 2048, 64), BF16),
-             ((8, 5, 2048, 64), BF16), ((8,), jnp.int32))
+@pytest.mark.parametrize("fold", [1, 2])
+def test_decode_attention_compiles(one_chip, fold):
+    # 8 slots x 2048 cache rows at smollm-360m widths: head-major
+    # (8, 5, 2048, 64), and lane-dense with two rows a 128-lane row.
+    kv = ((8, 5, 2048 // fold, 64 * fold), BF16)
+    _compile(lambda q, k, v, n: decode_attention(q, k, v, kv_len=n,
+                                                 fold=fold, **KW),
+             one_chip, ((8, 15, 64), BF16), kv, kv, ((8,), jnp.int32))
+
+
+# An HLO instruction that copies or transposes: its result type and op.
+_MOVE = re.compile(r"= \(?(\w+)\[([\d,]*)\]\S* (copy|copy-start|transpose)\(")
+
+
+@pytest.mark.parametrize("lane_dense", [True, False])
+def test_decode_layer_moves_no_kv_region(one_chip, lane_dense):
+    """One smollm-360m decode layer (64 slots x 2048 rows, 5 KV heads of
+    64, the regions donated) through ``_run_decode_attention``: with the
+    lane-dense regions ``kv_cache_spec`` gives, the compiled step copies
+    and transposes nothing of a region's size — the row is written in
+    place and the kernel reads the region as it rests.  The row-major
+    layout, as a control, is copied whole."""
+    from repro.core import regions
+    from repro.core.program import AttentionSpec, ProgramOp
+    from repro.runtime.executor import _run_decode_attention
+    slots, rows, H, KV, hd = 64, 2048, 15, 5, 64
+    spec = regions.kv_cache_spec("l0.k_cache", slots, rows, KV, hd, BF16)
+    fold = spec.fold if lane_dense else None
+    shape = spec.shape if lane_dense else (slots, rows, KV, hd)
+    op = ProgramOp(index=0, name="l0.attn", kernel="decode_attention",
+                   in_region=0, out_region=1, k_region=2, v_region=3,
+                   k_cache_region=4, v_cache_region=5,
+                   attn=AttentionSpec(heads=H, kv_heads=KV, head_dim=hd,
+                                      rope_theta=10000.0, block_q=1,
+                                      block_kv=rows, kv_fold=fold))
+
+    def layer(src, k_src, v_src, ck, cv, pos, live):
+        return _run_decode_attention(op, src, k_src, v_src, ck, cv, pos,
+                                     live, impl="pallas", interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((slots, H * hd), BF16), ((slots, KV * hd), BF16),
+        ((slots, KV * hd), BF16), (shape, BF16), (shape, BF16),
+        ((slots,), jnp.int32), ((slots,), jnp.bool_))]
+    text = (jax.jit(layer, donate_argnums=(3, 4)).lower(*args).compile()
+            .as_text())
+    assert "tpu_custom_call" in text
+    region = slots * rows * KV * hd
+    moved = [m.group(0) for m in _MOVE.finditer(text)
+             if math.prod(int(d) for d in m.group(2).split(",") if d)
+             == region]
+    if lane_dense:
+        assert not moved, moved
+    else:
+        assert moved
 
 
 @pytest.mark.parametrize("quant", [False, True])

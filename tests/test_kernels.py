@@ -169,6 +169,40 @@ def test_decode_attention_varlen(S, block):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("D,fold,block,dtype", [
+    (64, 2, 128, jnp.float32),     # smollm-360m's head_dim: 2 rows a lane row
+    (64, 2, 256, jnp.bfloat16),    # ... in the cache dtype it serves
+    (128, 1, 128, jnp.float32),    # head_dim 128: plain head-major
+    (16, 8, 64, jnp.float32),      # the smoke configs' head_dim
+])
+def test_decode_attention_folded_cache(D, fold, block, dtype):
+    """The kernel reads the lane-dense cache (B, Hkv, S/f, f*D) as it
+    rests and matches the oracle on the unfolded cache, GQA group 3.
+    Slots: an odd length (its last packed row half filled), a full ring
+    as after a wrap (kv_len == cache_len), a slot holding one row (a
+    fresh slot, or a dead one decode left unwritten), and one ending on
+    a block boundary."""
+    from repro.kernels.decode_attention import fold_kv
+    B, Hkv, G, S = 4, 2, 3, 256
+    ks = keys(3)
+    q = jax.random.normal(ks[0], (B, Hkv * G, D), jnp.float32)
+    k = (jax.random.normal(ks[1], (B, Hkv, S, D)) * 0.3).astype(dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, S, D)).astype(dtype)
+    kvl = jnp.array([S - 37, S, 1, block], jnp.int32)
+    ref = decode_attention_ref(q, k, v, kv_len=kvl)
+    kf, vf = fold_kv(k, fold), fold_kv(v, fold)
+    assert kf.shape == (B, Hkv, S // fold, fold * D)
+    out = decode_attention(q, kf, vf, kv_len=kvl, fold=fold,
+                           impl="pallas", block_kv=block, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    # the reference path unfolds the same operand
+    np.testing.assert_array_equal(
+        np.asarray(decode_attention(q, kf, vf, kv_len=kvl, fold=fold,
+                                    impl="reference")),
+        np.asarray(ref))
+
+
 def test_decode_attention_fp8_cache():
     B, Hq, Hkv, S, D = 2, 4, 2, 128, 32
     ks = keys(3)

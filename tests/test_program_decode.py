@@ -130,11 +130,128 @@ def test_decode_pallas_interpret_parity():
                                rtol=1e-4, atol=1e-4)
 
 
+# --- the lane-dense KV region layout ----------------------------------------------
+def _row_major_pair(cfg, slots, max_len, monkeypatch):
+    """The pair compiled with every KV region in the row-major
+    (slots, rows, KV, hd) layout, the one a head_dim that does not fold
+    keeps."""
+    from repro.core import regions
+    monkeypatch.setattr(regions, "kv_fold", lambda rows, head_dim: None)
+    transformer._compile_program_pair.cache_clear()
+    try:
+        return transformer.compile_program_pair(cfg, slots=slots,
+                                                max_len=max_len)
+    finally:
+        monkeypatch.undo()
+        transformer._compile_program_pair.cache_clear()
+
+
+@pytest.mark.parametrize("head_dim,fold,window", [
+    (16, 8, None), (64, 2, None), (128, 1, None), (16, 4, 4)])
+def test_folded_region_matches_row_major_layout(monkeypatch, head_dim,
+                                                fold, window):
+    """Whole prefill into slot 0, chunked prefill into slot 1, then
+    decode ticks past max_len (slot 1's ring wraps) with slot 0 dead on
+    one tick: the lane-dense regions, unfolded, are bitwise the
+    row-major regions after every call, and every logit is bitwise
+    equal.  The windowed case folds a 4-row ring into one row."""
+    from repro.kernels.decode_attention import unfold_kv
+    cfg = _cfg(n_layers=2, head_dim=head_dim, attn_window=window)
+    slots, max_len, P0, P1, chunk = 2, 16, 5, 11, 4
+    params, pair, state = _setup(cfg, slots, max_len)
+    flat = _row_major_pair(cfg, slots, max_len, monkeypatch)
+    flat_state = executor.init_program_state(flat)
+    for op in pair.decode.ops:
+        if op.kernel == "decode_attention":
+            assert op.attn.kv_fold == fold
+            assert flat.decode.op(op.name).attn.kv_fold is None
+    assert f"fold={fold}" in pair.decode.listing()
+    assert "fold=" not in flat.decode.listing()
+
+    def check(a, b, sa, sb):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for name, rid in pair.persistent.items():
+            got = unfold_kv(sa.caches[rid], fold)      # (slots, KV, S, hd)
+            want = sb.caches[flat.persistent[name]].transpose(0, 2, 1, 3)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want), err_msg=name)
+
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+               for n in (P0, P1)]
+    la, state = _prefill_slot(pair, params, state, 0, prompts[0], max_len)
+    lb, flat_state = _prefill_slot(flat, params, flat_state, 0, prompts[0],
+                                   max_len)
+    check(la, lb, state, flat_state)
+    padded = np.zeros((1, max_len), np.int32)
+    padded[0, :P1] = prompts[1]
+    for s0 in range(0, P1, chunk):
+        args = [jnp.asarray(x, jnp.int32)
+                for x in ([1], [s0], [min(s0 + chunk, P1)], [P1], [0])]
+        la, state = executor.run_prefill_chunk(
+            pair.prefill, params, jnp.asarray(padded), state, *args,
+            impl="reference")
+        lb, flat_state = executor.run_prefill_chunk(
+            flat.prefill, params, jnp.asarray(padded), flat_state, *args,
+            impl="reference")
+        check(la, lb, state, flat_state)
+    toks = jnp.asarray([3, 9], jnp.int32)
+    for t in range(7):                     # slot 1: 11 + 7 > 16 rows
+        mask = jnp.asarray([t != 2, True])
+        la, state = executor.run_decode(pair.decode, params, toks, state,
+                                        mask, impl="reference")
+        lb, flat_state = executor.run_decode(flat.decode, params, toks,
+                                             flat_state, mask,
+                                             impl="reference")
+        check(la, lb, state, flat_state)
+        toks = jnp.argmax(la, axis=-1).astype(jnp.int32)
+    assert list(np.asarray(state.lengths)) == [P0 + 6, P1 + 7]
+
+
+def test_head_dim_112_keeps_row_major_layout():
+    """A head_dim that neither divides nor is a multiple of 128 keeps
+    the row-major (slots, rows, KV, hd) region and the transposing
+    decode path; prefill + decode still match the legacy cache loop."""
+    cfg = _cfg(n_layers=2, head_dim=112)
+    slots, max_len, P = 2, 16, 5
+    params, pair, state = _setup(cfg, slots, max_len)
+    for r in pair.decode.plan.persistent_regions():
+        assert r.fold is None
+        assert r.shape == (slots, max_len, cfg.n_kv_heads, 112)
+    assert pair.decode.op("l0.attn").attn.kv_fold is None
+    assert "fold=" not in pair.decode.listing()
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, cfg.vocab, size=(slots, P)).astype(np.int32)
+    cache = transformer.init_cache(cfg, slots, max_len)
+    for t in range(P):
+        leg_logits, cache = transformer.decode_step(
+            params, cache, jnp.asarray(prompts[:, t]), cfg,
+            impl="reference")
+    for slot in range(slots):
+        logits, state = _prefill_slot(pair, params, state, slot,
+                                      prompts[slot], max_len)
+        np.testing.assert_allclose(np.asarray(logits[0, P - 1]),
+                                   np.asarray(leg_logits[slot]),
+                                   rtol=0, atol=1e-5)
+    toks = np.argmax(np.asarray(leg_logits), axis=-1).astype(np.int32)
+    for _ in range(3):
+        leg_logits, cache = transformer.decode_step(
+            params, cache, jnp.asarray(toks), cfg, impl="reference")
+        dec_logits, state = executor.run_decode(
+            pair.decode, params, jnp.asarray(toks), state,
+            impl="reference")
+        np.testing.assert_allclose(np.asarray(dec_logits),
+                                   np.asarray(leg_logits),
+                                   rtol=0, atol=1e-5)
+        toks = np.argmax(np.asarray(leg_logits), axis=-1).astype(np.int32)
+
+
 # --- persistent-region lifetime ----------------------------------------------------
 def test_persistent_regions_shared_and_never_reused():
     """The pair shares one persistent table: identical allocator-owned
     ids in both plans, disjoint from every transient region, never
-    assigned to an op output, and sized (slots, max_len, KV, hd)."""
+    assigned to an op output, and sized (slots, KV, max_len / f, f * hd),
+    lane-dense with f = 128 / hd recorded on the region."""
     cfg = _cfg()
     slots, max_len = 3, 16
     _, pair, _ = _setup(cfg, slots, max_len)
@@ -151,7 +268,10 @@ def test_persistent_regions_shared_and_never_reused():
         for name, rid in plan.persistent.items():
             r = plan.region(rid)
             assert r.kind == "persistent" and r.name == name
-            assert r.shape == (slots, max_len, cfg.n_kv_heads, cfg.hd)
+            f = 128 // cfg.hd
+            assert r.fold == f
+            assert r.shape == (slots, cfg.n_kv_heads, max_len // f,
+                               f * cfg.hd)
     # the transient footprint still matches the stateless lowering
     flat = transformer.compile_program(cfg, batch=1, seq=max_len)
     assert dec.n_pingpong == flat.plan.n_pingpong
@@ -203,7 +323,9 @@ def test_windowed_region_plan_shrinks_kv_to_window():
     cfg = _cfg(attn_window=W)
     for plan in (pair.prefill.plan, pair.decode.plan):
         for r in plan.persistent_regions():
-            assert r.shape == (slots, W, cfg.n_kv_heads, cfg.hd)
+            # a 4-row region folds its 4 rows into one 64-lane row
+            assert r.fold == W
+            assert r.shape == (slots, cfg.n_kv_heads, 1, W * cfg.hd)
     assert pair.persistent_bytes * (max_len // W) == full.persistent_bytes
     assert pair.decode.plan.n_pingpong == full.decode.plan.n_pingpong
     assert pair.decode.plan.n_pinned == full.decode.plan.n_pinned
@@ -216,6 +338,7 @@ def test_windowed_region_plan_shrinks_kv_to_window():
         assert op.attn.window == W
         assert (op.attn.block_q, op.attn.block_kv) == want
     assert f"win={W}" in pair.decode.listing()
+    assert f"fold={W}" in pair.decode.listing()
 
 
 def test_windowed_prefill_and_decode_match_legacy_past_max_len():
@@ -435,7 +558,8 @@ def test_serving_decode_dispatches_decode_attention(monkeypatch):
     real_flash = executor.flash_attention
 
     def spy_decode(q, k, v, **kw):
-        decode_calls.append((q.shape, k.shape, kw.get("block_kv")))
+        decode_calls.append((q.shape, k.shape, kw.get("block_kv"),
+                             kw.get("fold")))
         return real_decode(q, k, v, **kw)
 
     def spy_flash(q, k, v, **kw):
@@ -454,9 +578,11 @@ def test_serving_decode_dispatches_decode_attention(monkeypatch):
     # decode_attention ops exclusively
     assert len(flash_calls) == cfg.n_layers
     assert len(decode_calls) == cfg.n_layers
-    qshape, kshape, bkv = decode_calls[0]
+    qshape, kshape, bkv, fold = decode_calls[0]
     assert qshape == (2, cfg.n_heads, cfg.hd)
-    assert kshape == (2, cfg.n_kv_heads, 16, cfg.hd)
+    # the lane-dense region as it rests: 16 rows folded 8 to a row
+    assert fold == 8
+    assert kshape == (2, cfg.n_kv_heads, 16 // fold, fold * cfg.hd)
     pair = transformer.compile_program_pair(cfg, slots=2, max_len=16)
     assert bkv == pair.decode.op("l0.attn").attn.block_kv
 

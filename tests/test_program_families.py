@@ -180,8 +180,8 @@ def test_ssm_state_regions_are_o1_in_seq_len():
     recur = lambda specs: [s for s in specs if "ssm" in s.name
                            or "conv" in s.name]
     assert recur(zs) == recur(zl)
-    kv_rows = lambda specs: {s.name: s.shape[1] for s in specs
-                             if s not in recur(specs)}
+    kv_rows = lambda specs: {s.name: regions.kv_cache_rows(s.shape, s.fold)
+                             for s in specs if s not in recur(specs)}
     assert all(r == 16 for r in kv_rows(zs).values())
     assert all(r == min(1024, zcfg.attn_window)
                for r in kv_rows(zl).values())
