@@ -5,11 +5,15 @@ The loop of the mix's arrival process (``bench/loops/<kind>.py``) calls
 span and the work it completed (the kind's ``on_output`` counts it),
 and each request its due time and the times its outputs came back.
 Outputs come back when ``step`` returns, so every output of a step
-carries that step's end time.  Host spans around submit and step go into
-the profiler's trace when one is running.
+carries that step's end time.  Around each step, outside its host span,
+the client also reads what the engine reports about itself: the seconds
+of each of its ``serving.*`` phases (``obs.phase_seconds()``) and its
+counters, and puts the step's differences on the ``Tick``.  Host spans
+around submit and step go into the profiler's trace when one is running.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 
@@ -29,6 +33,8 @@ class Tick:
     live: int = 0                                  # slots that advanced
     finished: int = 0                              # requests completed
     traced: bool = False
+    phases: dict = field(default_factory=dict)     # serving.* phase -> s
+    counters: dict = field(default_factory=dict)   # flat name -> delta
 
 
 @dataclass
@@ -54,6 +60,32 @@ class Window:
     @property
     def seconds(self) -> float:
         return self.end - self.start
+
+
+def counter_values(registry) -> dict:
+    """{(name, labels): value} of every counter of the engine's
+    ``MetricsRegistry``, without the histogram percentiles that its
+    ``snapshot()`` computes (about 70 us a call on a CPU core)."""
+    return {(fam.name, labels): c.value
+            for fam in registry._families.values() if fam.kind == "counter"
+            for labels, c in fam.children.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def flat_name(key: tuple) -> str:
+    """A counter's name as ``snapshot()`` gives it:
+    ``serving_phase_spans_total{phase="serving.step"}``."""
+    name, labels = key
+    if not labels:
+        return name
+    return name + "{" + ",".join(f'{k}="{v}"' for k, v in labels) + "}"
+
+
+def _moved(after: dict, before: dict) -> dict:
+    """The entries of ``after`` that differ from ``before``, as
+    differences."""
+    return {k: v - before.get(k, 0.0) for k, v in after.items()
+            if v != before.get(k, 0.0)}
 
 
 class Client:
@@ -107,11 +139,16 @@ class Client:
             self._tracing = False
 
     def step(self) -> Tick:
+        obs = self.eng.obs
+        phases, counters = obs.phase_seconds(), counter_values(obs.registry)
         with TraceAnnotation("bench.step"):
             t0 = clock()
             finished = self.eng.step()
             t1 = clock()
-        tick = Tick(t0, t1, traced=self._tracing)
+        moved = _moved(counter_values(obs.registry), counters)
+        tick = Tick(t0, t1, traced=self._tracing,
+                    phases=_moved(obs.phase_seconds(), phases),
+                    counters={flat_name(k): v for k, v in moved.items()})
         for req in list(self.eng.live.values()) + list(finished):
             rec = req.bench_rec
             before, after = len(rec.out), len(req.out_tokens)

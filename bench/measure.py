@@ -1,7 +1,7 @@
 """Arithmetic shared by the metric readers: rates and tails of the
-window, work of the traced steps, step utilization, idle share and
-kernel roofline share.  A reader that finds nothing to read returns
-None, never 0."""
+window, work of the traced steps, step utilization, the program's phase
+shares, idle share and kernel roofline share.  A reader that finds
+nothing to read returns None, never 0."""
 from __future__ import annotations
 
 import numpy as np
@@ -46,6 +46,32 @@ def step_mfu(run) -> float | None:
     kind = spec.kind(run.cell.config)
     flops = sum(kind.tick_flops(run.cell.config, t) for t in ticks)
     return 100.0 * flops / (wall * run.peak["flops_per_s"])
+
+
+STEP_PHASE = "serving.step"
+
+
+def before_trace(ticks) -> list:
+    """The ticks before the first traced one.  Once the profiler has
+    run, the process stays changed: alexnet-owt's ``serving.stage``
+    takes about 40 ms a batch before a trace and about 4 ms after it
+    (TPU v5e), so untraced ticks after the trace are not the ones an
+    untraced run has."""
+    for i, t in enumerate(ticks):
+        if t.traced:
+            return ticks[:i]
+    return list(ticks)
+
+
+def phase_share(ticks, names) -> float | None:
+    """% of the ``serving.step`` seconds of the ticks before the trace
+    spent in the program's phases ``names`` (``Tick.phases``)."""
+    ticks = before_trace(ticks)
+    step = sum(t.phases.get(STEP_PHASE, 0.0) for t in ticks)
+    if step <= 0:
+        return None
+    return 100.0 * sum(t.phases.get(n, 0.0) for t in ticks
+                       for n in names) / step
 
 
 def idle_share(run) -> float | None:

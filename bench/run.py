@@ -62,7 +62,7 @@ _IMPORTED = time.perf_counter()
 class RunData:
     """What a metric reader (bench/metrics/<name>.py) reads."""
     cell: spec.Cell
-    window: object            # drive.Window
+    window: object            # drive.Window (Ticks: phases, counters)
     setup_s: float
     peak: dict                # bench/peaks.json entry of this device
     trace: object | None      # trace_reduce.Trace of the traced part
@@ -148,7 +148,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              engine_hook=None, peak: dict | None = None) -> dict:
     """One run; returns the result object.  ``controls`` adds the
     control's readings and ``shared`` keeps the compiled Program for the
-    next run in this process (bench/control.py); ``engine_hook(engine)``
+    next run in this process (bench/control.py), and the run's readings
+    and ``RunData`` (bench/phases.py); ``engine_hook(engine)``
     may break the engine underneath the timed path after its warm-up
     (the fault tests use it)."""
     import jax
@@ -202,8 +203,6 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del eng, client
     gc.collect()
 
-    if shared is not None:
-        shared["window"] = w
     attempted = sum(any(w.start <= x <= w.end for x in r.times)
                     for r in w.recs.values())
     readings = kind.compare(config, params, t, w, traffic.rng_for(seed, 3),
@@ -216,6 +215,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             compared[name] = {"value": value, "limit": None}
     run = RunData(cell, w, setup_s, peak or peak_for(devs[0].device_kind),
                   reduced)
+    if shared is not None:
+        shared["run"] = run
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:

@@ -124,3 +124,19 @@ def test_the_control_fails_the_limit(name, number, shared):
     c = r["compared"]
     assert c[number]["value"] <= c[number]["limit"]
     assert c[f"control_{number}.int8"]["value"] > c[number]["limit"]
+
+
+@pytest.mark.parametrize("name,counter", [
+    ("lm.backlog", "serving_decode_ticks_total"),
+    ("cnn.backlog", 'serving_phase_spans_total{phase="serving.stage"}')])
+def test_ticks_carry_the_programs_phases_and_counters(name, counter, shared):
+    """Every step of the window carries the seconds of the engine's
+    phases and the differences of its counters over that step."""
+    cell_run(name, shared)
+    ticks = shared[name]["run"].window.ticks
+    assert ticks
+    for t in ticks:
+        assert 0 < t.phases["serving.step"] <= t.t1 - t.t0
+        assert t.counters[
+            'serving_phase_spans_total{phase="serving.step"}'] == 1
+    assert sum(t.counters.get(counter, 0) for t in ticks) > 0

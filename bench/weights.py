@@ -5,8 +5,11 @@ The tree has the names and shapes the program's engine reads, laid out
 by the configuration's kind (``bench/kinds/<kind>.py::weight_shapes``)
 from the benchmark's own configuration file, so both the program and
 the plain reference are handed the same arrays and neither makes them.
-Every matrix is a fan-in scaled normal, the embedding a normal of
-standard deviation 0.02, norm weights ones and biases zeros.
+A kind that defines ``init_leaf(path, key, shape, dtype, stacked)``
+makes the leaves for which it returns an array (a leaf that must keep a
+sign or a range, such as a state-space model's ``A_log``).  Every other
+matrix is a fan-in scaled normal, the embedding a normal of standard
+deviation 0.02, norm weights ones and biases zeros.
 """
 from __future__ import annotations
 
@@ -34,7 +37,8 @@ def _leaf(key, path: str, shape: tuple, dtype, stacked: bool):
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
-def _make(shapes: dict, dtype, stacked_under: str | None, key):
+def _make(shapes: dict, dtype, stacked_under: str | None, key,
+          init_leaf=None):
     leaves = []
 
     def walk(sub, prefix):
@@ -53,13 +57,18 @@ def _make(shapes: dict, dtype, stacked_under: str | None, key):
         *parents, name = path.split("/")
         for p in parents:
             node = node.setdefault(p, {})
-        node[name] = _leaf(kk, path, shape, dtype, stacked)
+        leaf = (None if init_leaf is None
+                else init_leaf(path, kk, shape, dtype, stacked))
+        node[name] = (_leaf(kk, path, shape, dtype, stacked)
+                      if leaf is None else leaf)
     return out
 
 
 def make(config: dict, seed: int) -> dict:
     from bench import spec
-    shapes, dtype, stacked = spec.kind(config).weight_shapes(config)
+    kind = spec.kind(config)
+    shapes, dtype, stacked = kind.weight_shapes(config)
     dtype = jnp.dtype(dtype)
-    fn = jax.jit(lambda k: _make(shapes, dtype, stacked, k))
+    init_leaf = getattr(kind, "init_leaf", None)
+    fn = jax.jit(lambda k: _make(shapes, dtype, stacked, k, init_leaf))
     return jax.block_until_ready(fn(key_for(seed)))
